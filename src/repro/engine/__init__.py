@@ -39,8 +39,8 @@ from repro.engine.backend import (
 from repro.engine.execute import (
     DecodeState, apply, decode_state_batch_init, decode_state_gather,
     decode_state_init, decode_state_scatter, decode_step, make_apply_fn,
-    make_decode_step_fn, make_prefill_chunk_fn, make_prefill_fn, prefill,
-    prefill_chunk,
+    make_decode_step_fn, make_prefill_chunk_fn, make_prefill_fn,
+    place_params, prefill, prefill_chunk,
 )
 from repro.engine.layout import (
     ProjUnit, SpikeEdge, TokStage, block_layout, lm_block_layout,
@@ -62,7 +62,7 @@ __all__ = [
     "DecodeState", "apply", "decode_state_batch_init", "decode_state_gather",
     "decode_state_init", "decode_state_scatter", "decode_step",
     "make_apply_fn", "make_decode_step_fn", "make_prefill_chunk_fn",
-    "make_prefill_fn", "prefill", "prefill_chunk",
+    "make_prefill_fn", "place_params", "prefill", "prefill_chunk",
     "ProjUnit", "SpikeEdge", "TokStage", "block_layout", "lm_block_layout",
     "lm_decode_spike_edges", "lm_spike_edges", "spike_edges",
     "tokenizer_layout",

@@ -14,7 +14,7 @@ import math
 from collections import Counter
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 
 _BN_PRIMS = ("rsqrt",)  # eval-mode BN lowers to rsqrt(var+eps); VISION-ONLY
@@ -80,14 +80,14 @@ def rmsnorm_op_count(fn, *args, **kwargs) -> int:
     """Number of standalone RMSNorm applications in ``fn``'s jaxpr.
 
     ``models.layers.rmsnorm_apply`` is jitted, so every application is a
-    named ``pjit`` node -- the RMSNorm counterpart of :func:`bn_op_count`
+    named ``jit`` node -- the RMSNorm counterpart of :func:`bn_op_count`
     (RMSNorm's rsqrt cannot be the signature here: the folded units keep a
     gain-free data-dependent normalizer, which also uses rsqrt; what folding
     removes is the parameterised norm LAYER, counted by name).
     """
     closed = jax.make_jaxpr(fn)(*args, **kwargs)
     return sum(1 for eqn in iter_eqns(closed.jaxpr)
-               if eqn.primitive.name == "pjit"
+               if eqn.primitive.name == "jit"
                and eqn.params.get("name") == "rmsnorm_apply")
 
 

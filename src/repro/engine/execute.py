@@ -907,6 +907,23 @@ def make_apply_fn(plan: DeployPlan):
                          body_specs, out_specs)
 
 
+def place_params(plan: DeployPlan) -> DeployPlan:
+    """Commit a sharded plan's params to its mesh, each leaf with the
+    PartitionSpec its executor reads it under, so serving moves no weights
+    per call.  Plans compiled without ``mesh=`` come back unchanged."""
+    meta = plan.meta
+    if meta.sharding is None:
+        return plan
+    from jax.sharding import NamedSharding
+
+    mesh, _, _ = _sharded_context(meta)
+    specs = _param_specs(meta, plan.params)
+    params = jax.tree_util.tree_map(
+        lambda a, s: jax.device_put(a, NamedSharding(mesh, s)),
+        plan.params, specs)
+    return DeployPlan(meta=meta, params=params)
+
+
 def apply(plan: DeployPlan, batch) -> jax.Array:
     """One-shot convenience: run the plan on a batch (images or tokens)."""
     return make_apply_fn(plan)(plan.params, batch)

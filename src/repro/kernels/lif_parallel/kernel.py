@@ -101,14 +101,17 @@ def _pack_rows(spikes):
 
     The packing runs inside the kernel epilogue, so the spike train leaves
     VMEM already packed -- HBM sees one uint32 word per neuron per 32 steps
-    instead of T f32 writes (the tentpole's traffic win starts here).
+    instead of T f32 writes (the packed datapath's traffic win starts here).
+    The 0/1 spike crosses to uint32 through int32 because Mosaic has no
+    float32 -> uint32 cast (the detour is exact for 0/1).
     """
     t_total = len(spikes)
     words = []
     for w in range(-(-t_total // _WORD_BITS)):
         acc = jnp.zeros_like(spikes[0], dtype=jnp.uint32)
         for t in range(w * _WORD_BITS, min((w + 1) * _WORD_BITS, t_total)):
-            acc = acc | (spikes[t].astype(jnp.uint32) << jnp.uint32(t % _WORD_BITS))
+            bit = spikes[t].astype(jnp.int32).astype(jnp.uint32)
+            acc = acc | (bit << jnp.uint32(t % _WORD_BITS))
         words.append(acc)
     return words
 

@@ -44,6 +44,14 @@ def _tile(dim: int, prefs: tuple[int, ...]) -> int:
     return dim
 
 
+def bitplane(words: jax.Array, bit: int) -> jax.Array:
+    """Bitplane ``bit`` of a uint32 word tile as f32 {0, 1}: a shift-and-mask
+    in VMEM.  The 0/1 value crosses to f32 through int32 because Mosaic has
+    no uint32 -> float32 cast (the detour is exact for 0/1)."""
+    plane = (words >> jnp.uint32(bit)) & jnp.uint32(1)
+    return plane.astype(jnp.int32).astype(jnp.float32)
+
+
 def packed_matmul_kernel(xw_ref, w_ref, o_ref, acc_ref, *, t_total: int):
     """GEMM on bit-packed spike operands: unpack per-tile in VMEM.
 
@@ -61,8 +69,8 @@ def packed_matmul_kernel(xw_ref, w_ref, o_ref, acc_ref, *, t_total: int):
     words = xw_ref[...]
     w = w_ref[...]
     for t in range(t_total):
-        xt = ((words >> jnp.uint32(t)) & jnp.uint32(1)).astype(jnp.float32)
-        acc_ref[t] += jnp.dot(xt, w, preferred_element_type=jnp.float32)
+        acc_ref[t] += jnp.dot(bitplane(words, t), w,
+                              preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _write():
@@ -77,21 +85,24 @@ def sparse_packed_matmul_kernel(occ_ref, xw_ref, w_ref, o_ref, acc_ref, *,
     contribution to the accumulator is exactly 0.0 -- and saves both the T
     shift-and-mask unpacks and the T MXU dots of a dead tile.
 
-    ``occ_ref`` is a (1, 1) uint32 tile of the per-(M-tile, K-tile) popcount
-    map derived from the pack-time occupancy map (ops.py reduces it to this
-    grid's tiling).
+    ``occ_ref`` is the whole per-(M-tile, K-tile) popcount map, flattened
+    row-major to int32 and scalar-prefetched into SMEM (ops.py reduces the
+    pack-time occupancy map to this grid's tiling); a (1, 1) VMEM block of it
+    would violate the (8, 128) tiling rule.
     """
     @pl.when(pl.program_id(2) == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(occ_ref[0, 0] > 0)
+    tile = pl.program_id(0) * pl.num_programs(2) + pl.program_id(2)
+
+    @pl.when(occ_ref[tile] > 0)
     def _body():
         words = xw_ref[...]
         w = w_ref[...]
         for t in range(t_total):
-            xt = ((words >> jnp.uint32(t)) & jnp.uint32(1)).astype(jnp.float32)
-            acc_ref[t] += jnp.dot(xt, w, preferred_element_type=jnp.float32)
+            acc_ref[t] += jnp.dot(bitplane(words, t), w,
+                                  preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _write():
@@ -120,17 +131,20 @@ def sparse_packed_spike_matmul_fwd(xw: jax.Array, w: jax.Array,
     kern = functools.partial(sparse_packed_matmul_kernel, t_total=t_total)
     return pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, l: (i, l)),
-            pl.BlockSpec((bm, bk), lambda i, j, l: (i, l)),
-            pl.BlockSpec((bk, bc), lambda i, j, l: (l, j)),
-        ],
-        out_specs=pl.BlockSpec((t_total, bm, bc), lambda i, j, l: (0, i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, l, occ: (i, l)),
+                pl.BlockSpec((bk, bc), lambda i, j, l, occ: (l, j)),
+            ],
+            out_specs=pl.BlockSpec((t_total, bm, bc),
+                                   lambda i, j, l, occ: (0, i, j)),
+            scratch_shapes=[pltpu.VMEM((t_total, bm, bc), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((t_total, m, c), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((t_total, bm, bc), jnp.float32)],
         interpret=interpret,
-    )(occ_tiles, xw, w)
+    )(occ_tiles.reshape(-1).astype(jnp.int32), xw, w)
 
 
 def packed_spike_matmul_fwd(xw: jax.Array, w: jax.Array, *, t_total: int,

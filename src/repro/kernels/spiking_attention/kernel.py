@@ -27,6 +27,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.spike_matmul.kernel import bitplane
 
 
 def _causal_tile_mask(bq: int, m: int):
@@ -87,17 +90,18 @@ def packed_ssa_kernel(qw_ref, kw_ref, vw_ref, o_ref, *, t_total: int,
     of word ``t // 32`` is the spike at time step ``t`` (the
     ``repro.core.packing`` layout), so one HBM read of each operand tile
     covers ALL T time steps; the dense kernel reads T f32 planes.  Each
-    bitplane is extracted with a shift-and-mask (exactly as
-    ``packed_matmul_kernel`` does) and fed to the two MXU contractions; the
-    T output planes share the q/k/v words already resident in VMEM.
+    bitplane is extracted with the GEMM kernel's shift-and-mask
+    (:func:`~repro.kernels.spike_matmul.kernel.bitplane`) and fed to the
+    two MXU contractions; the T output planes share the q/k/v words already
+    resident in VMEM.
     """
     mask = (_causal_tile_mask(qw_ref.shape[2], kw_ref.shape[2])
             if causal else None)
     for t in range(t_total):
         wi, bit = divmod(t, 32)
-        qt = ((qw_ref[wi, 0] >> jnp.uint32(bit)) & jnp.uint32(1)).astype(jnp.float32)
-        kt = ((kw_ref[wi, 0] >> jnp.uint32(bit)) & jnp.uint32(1)).astype(jnp.float32)
-        vt = ((vw_ref[wi, 0] >> jnp.uint32(bit)) & jnp.uint32(1)).astype(jnp.float32)
+        qt = bitplane(qw_ref[wi, 0], bit)
+        kt = bitplane(kw_ref[wi, 0], bit)
+        vt = bitplane(vw_ref[wi, 0], bit)
         scores = jnp.dot(qt, kt.T, preferred_element_type=jnp.float32)
         if mask is not None:
             scores = jnp.where(mask, scores, 0.0)
@@ -108,30 +112,33 @@ def packed_ssa_kernel(qw_ref, kw_ref, vw_ref, o_ref, *, t_total: int,
 def sparse_packed_ssa_kernel(occ_ref, qw_ref, kw_ref, vw_ref, o_ref, *,
                              t_total: int, scale: float, causal: bool):
     """Occupancy-predicated packed SSA: each bitplane's two MXU contractions
-    run only when the plane is live for this (b, h) fold -- ``occ_ref[0, t]``
-    is 1 iff q, k AND v all carry at least one spike at time step ``t``
-    (ops.py derives it from a bitwise-OR reduce of the words).  A dead plane's
-    output is exactly zero (one of the two contractions has an all-zero
-    operand), so it is written as zeros without unpacking anything --
-    bit-exact vs :func:`packed_ssa_kernel` because bitplanes are independent.
+    run only when the plane is live for this (b, h) fold -- ``occ_ref`` is
+    the whole (G, T) liveness map, flattened to int32 and scalar-prefetched
+    into SMEM; entry ``g*T + t`` is 1 iff q, k AND v all carry at least one
+    spike at time step ``t`` (ops.py derives it from a bitwise-OR reduce of
+    the words).  A dead plane's output is exactly zero (one of the two
+    contractions has an all-zero operand), so it is written as zeros without
+    unpacking anything -- bit-exact vs :func:`packed_ssa_kernel` because
+    bitplanes are independent.
     """
     mask = (_causal_tile_mask(qw_ref.shape[2], kw_ref.shape[2])
             if causal else None)
+    base = pl.program_id(0) * t_total
     for t in range(t_total):
         wi, bit = divmod(t, 32)
 
-        @pl.when(occ_ref[0, t] > 0)
+        @pl.when(occ_ref[base + t] > 0)
         def _live(t=t, wi=wi, bit=bit):
-            qt = ((qw_ref[wi, 0] >> jnp.uint32(bit)) & jnp.uint32(1)).astype(jnp.float32)
-            kt = ((kw_ref[wi, 0] >> jnp.uint32(bit)) & jnp.uint32(1)).astype(jnp.float32)
-            vt = ((vw_ref[wi, 0] >> jnp.uint32(bit)) & jnp.uint32(1)).astype(jnp.float32)
+            qt = bitplane(qw_ref[wi, 0], bit)
+            kt = bitplane(kw_ref[wi, 0], bit)
+            vt = bitplane(vw_ref[wi, 0], bit)
             scores = jnp.dot(qt, kt.T, preferred_element_type=jnp.float32)
             if mask is not None:
                 scores = jnp.where(mask, scores, 0.0)
             out = jnp.dot(scores, vt, preferred_element_type=jnp.float32) * scale
             o_ref[t, 0] = out.astype(o_ref.dtype)
 
-        @pl.when(occ_ref[0, t] == 0)
+        @pl.when(occ_ref[base + t] == 0)
         def _dead(t=t):
             o_ref[t, 0] = jnp.zeros_like(o_ref[t, 0])
 
@@ -139,26 +146,31 @@ def sparse_packed_ssa_kernel(occ_ref, qw_ref, kw_ref, vw_ref, o_ref, *,
 def sparse_packed_ssa_fwd(qw: jax.Array, kw: jax.Array, vw: jax.Array,
                           occ: jax.Array, *, t_total: int, scale: float,
                           interpret: bool, causal: bool = False) -> jax.Array:
-    """Sparse variant of :func:`packed_ssa_fwd`; ``occ`` is the (G, T_pad)
-    uint32 per-(fold, bitplane) liveness map."""
+    """Sparse variant of :func:`packed_ssa_fwd`; ``occ`` is the (G, T)
+    per-(fold, bitplane) liveness map."""
     w, g, n, d = qw.shape
     m = kw.shape[2]
     bq = _block_q(n)
     grid = (g, n // bq)
+    if occ.shape != (g, t_total):
+        raise ValueError(f"liveness map {occ.shape} is not ({g}, {t_total})")
     return pl.pallas_call(
         functools.partial(sparse_packed_ssa_kernel, t_total=t_total,
                           scale=scale, causal=causal),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, occ.shape[1]), lambda gi, qi: (gi, 0)),
-            pl.BlockSpec((w, 1, bq, d), lambda gi, qi: (0, gi, qi, 0)),
-            pl.BlockSpec((w, 1, m, d), lambda gi, qi: (0, gi, 0, 0)),
-            pl.BlockSpec((w, 1, m, d), lambda gi, qi: (0, gi, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((t_total, 1, bq, d), lambda gi, qi: (0, gi, qi, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((w, 1, bq, d), lambda gi, qi, occ: (0, gi, qi, 0)),
+                pl.BlockSpec((w, 1, m, d), lambda gi, qi, occ: (0, gi, 0, 0)),
+                pl.BlockSpec((w, 1, m, d), lambda gi, qi, occ: (0, gi, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((t_total, 1, bq, d),
+                                   lambda gi, qi, occ: (0, gi, qi, 0)),
+        ),
         out_shape=jax.ShapeDtypeStruct((t_total, g, n, d), jnp.float32),
         interpret=interpret,
-    )(occ, qw, kw, vw)
+    )(occ.reshape(-1).astype(jnp.int32), qw, kw, vw)
 
 
 def packed_ssa_fwd(qw: jax.Array, kw: jax.Array, vw: jax.Array, *,

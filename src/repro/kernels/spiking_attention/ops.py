@@ -113,22 +113,20 @@ def packed_ssa_op(qw: jax.Array, kw: jax.Array, vw: jax.Array, *, t: int,
 
 
 def _plane_liveness(qf, kf, vf, t: int) -> jax.Array:
-    """Per-(fold, bitplane) liveness of three packed operands: (G, T_pad)
+    """Per-(fold, bitplane) liveness of three packed operands: (G, T)
     uint32, 1 iff q, k and v all spike somewhere at that time step.
 
     One bitwise-OR reduce over the token/feature axes collapses each operand
     to (W, G) or-words whose bit ``t % 32`` says "plane t has a spike" -- the
     SSA analogue of the GEMM's popcount occupancy map, at bitplane (not tile)
-    granularity and computed without unpacking.  The lane axis is padded to
-    128 for the kernel's occupancy operand.
+    granularity and computed without unpacking.
     """
     ors = [jax.lax.reduce(x, jnp.uint32(0), jax.lax.bitwise_or, (2, 3))
            for x in (qf, kf, vf)]
     comb = ors[0] & ors[1] & ors[2]                       # (W, G)
     steps = jnp.arange(t, dtype=jnp.uint32)
     live = (comb[steps // 32] >> (steps % 32)[:, None]) & jnp.uint32(1)
-    occ = live.T                                          # (G, T)
-    return jnp.pad(occ, ((0, 0), (0, (-t) % 128)))
+    return live.T                                         # (G, T)
 
 
 @functools.partial(jax.jit, static_argnames=("t", "scale", "interpret", "causal"))

@@ -29,7 +29,8 @@ attention heads shard over the model axis, and under a packed backend every
 cross-device spike edge moves uint32 bitplane words.  The shape is ELASTIC:
 when the live fleet is short (a dead shard), ``fault_tolerance.plan_remesh``
 shrinks the data axis and the slot count proportionally -- capacity degrades,
-the service stays up.
+the service stays up.  A fleet too small for even one model group is an
+error: serving never drops to one device behind the caller's back.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b_smoke \
@@ -74,8 +75,9 @@ def _elastic_mesh(shape, slots: int, *, verbose: bool = True):
     Routes the requested (data, model) shape through
     :func:`repro.distributed.fault_tolerance.plan_remesh`: a dead shard
     SHRINKS capacity (fewer data replicas, proportionally fewer slots)
-    instead of killing the service; only a fleet too small for even one
-    model group aborts to single-device serving.
+    instead of killing the service.  A fleet too small for even one model
+    group raises: serving a mesh request on one device would hide the
+    missing devices.
     """
     from repro.distributed.fault_tolerance import plan_remesh
 
@@ -90,11 +92,9 @@ def _elastic_mesh(shape, slots: int, *, verbose: bool = True):
                   f"({plan.new_global_batch} slots) -- capacity shrinks, "
                   "service stays up")
         return plan.new_shape, max(1, plan.new_global_batch)
-    if verbose:
-        print(f"[serve] mesh {tuple(shape)} infeasible on "
-              f"{jax.device_count()} device(s) (model axis alone does not "
-              "fit): falling back to single-device serving")
-    return (1, 1), slots
+    raise RuntimeError(
+        f"mesh {tuple(shape)} is infeasible on {jax.device_count()} "
+        "device(s): the model axis alone does not fit")
 
 
 def _pad_batch(x, mult: int):
@@ -192,7 +192,9 @@ def serve(arch: str, *, num_requests: int, prompt_len: int, max_new: int,
         "decode_tokens_per_s": tot / decode_s if decode_s else float("inf"),
     }
     if verbose:
-        print(f"[serve] {num_requests} requests on CPU: prefill {fed} prompt "
+        dev = jax.devices()[0]
+        print(f"[serve] {num_requests} requests on {dev.platform} "
+              f"({dev.device_kind}): prefill {fed} prompt "
               f"tokens in {prefill_s:.2f}s "
               f"({stats['prefill_tokens_per_s']:.1f} tok/s), decode {tot} new "
               f"tokens in {decode_s:.2f}s "
@@ -204,15 +206,18 @@ def serve(arch: str, *, num_requests: int, prompt_len: int, max_new: int,
 
 def serve_vision(arch: str, *, num_requests: int, slots: int = 4,
                  backend: str = "jnp", mesh=None, seed: int = 0,
-                 verbose: bool = True):
+                 verbose: bool = True, return_stats: bool = False):
     """Serve a vision Spikformer through the deploy engine.
 
     The (params, state, cfg) triple is compiled ONCE into a deploy plan --
     ConvBN/LinearBN folded, IAND fused into the neuron epilogue, backend a
     plan property -- then slot batches of images run the jitted executor.
-    ``mesh`` ("dxm" or (data, model)) compiles a mesh-sharded plan and fans
-    slot batches over the data axis; the shape degrades elastically
-    (:func:`_elastic_mesh`) when devices are missing.
+    ``mesh`` ("dxm" or (data, model)) compiles a mesh-sharded plan, commits
+    its weights to the mesh and fans slot batches over the data axis; the
+    shape degrades elastically (:func:`_elastic_mesh`) when devices are
+    missing.  ``return_stats`` also returns ``{"logits": (N, classes)
+    array, "wall_s", "plan", "executor"}`` -- the executor is the jitted
+    ``fn(params, images)`` that served.
     """
     from repro import engine
     from repro.configs.spike_iand_former import get_vision_config
@@ -225,7 +230,8 @@ def serve_vision(arch: str, *, num_requests: int, slots: int = 4,
         data_par = mesh[0]
     cfg = get_vision_config(arch)
     params, state = sf.init(jax.random.PRNGKey(seed), cfg)
-    plan = engine.compile_plan(params, state, cfg, backend=backend, mesh=mesh)
+    plan = engine.place_params(
+        engine.compile_plan(params, state, cfg, backend=backend, mesh=mesh))
     step = jax.jit(engine.make_apply_fn(plan))
 
     imgs = jax.random.uniform(
@@ -239,12 +245,12 @@ def serve_vision(arch: str, *, num_requests: int, slots: int = 4,
         warm, _ = _pad_batch(imgs[:min(bp, num_requests)], data_par)
         jax.block_until_ready(step(plan.params, warm))
 
-    done, t0 = [], time.perf_counter()
+    done, rows, t0 = [], [], time.perf_counter()
     for start in range(0, num_requests, slots):
         batch, b = _pad_batch(imgs[start : start + slots], data_par)
-        logits = step(plan.params, batch)
-        classes = np.asarray(jnp.argmax(logits[:b], axis=-1))
-        for j, c in enumerate(classes):
+        logits = np.asarray(step(plan.params, batch)[:b])
+        rows.append(logits)
+        for j, c in enumerate(logits.argmax(axis=-1)):
             done.append((start + j, int(c)))
         if verbose:
             print(f"[serve] slot batch {start//slots}: classified "
@@ -261,6 +267,9 @@ def serve_vision(arch: str, *, num_requests: int, slots: int = 4,
               f"LIF+IAND dispatches, backend={stats['backend']}"
               f"{', packed spikes' if stats['packed'] else ''}"
               f"{' + occupancy skip' if stats['sparse'] else ''})")
+    if return_stats:
+        return done, {"logits": np.concatenate(rows), "wall_s": dt,
+                      "plan": plan, "executor": step}
     return done
 
 
@@ -287,8 +296,8 @@ def _compile_lm_serving(arch: str, *, backend, ordering, mesh, slots, seed,
         data_par = mesh[0]
     cfg = spiking_lm_config(arch)
     params = slm.init_spiking_lm(jax.random.PRNGKey(seed), cfg)
-    plan = engine.compile_plan(params, None, cfg, backend=backend,
-                               ordering=ordering, mesh=mesh)
+    plan = engine.place_params(engine.compile_plan(
+        params, None, cfg, backend=backend, ordering=ordering, mesh=mesh))
     return cfg, plan, data_par, slots
 
 
@@ -424,6 +433,7 @@ def serve_spiking_lm_continuous(arch: str, *, num_requests: int,
     force ragged completion.  ``prefill_chunk`` switches admission to
     decode-interleaved chunked prefill (one resumable chunk per scheduler
     tick -- bounds the decode stall of a long-prompt admission).
+    ``return_stats`` also returns the scheduler's counters plus ``plan``.
     """
     from repro import engine
     from repro.launch.scheduler import ContinuousScheduler
@@ -451,7 +461,8 @@ def serve_spiking_lm_continuous(arch: str, *, num_requests: int,
     dt = time.perf_counter() - t0
     done = [(r.rid, np.asarray(r.tokens, np.int32)) for r in completed]
     sstats = sched.stats()
-    sstats.update(wall_s=dt, warm_prefill_shapes=warmed, warm_step_shapes=1)
+    sstats.update(wall_s=dt, warm_prefill_shapes=warmed, warm_step_shapes=1,
+                  plan=plan)
     if verbose:
         stats = engine.plan_stats(plan)
         print(f"[serve] continuous: {len(completed)}/{num_requests} requests, "
@@ -468,6 +479,9 @@ def serve_spiking_lm_continuous(arch: str, *, num_requests: int,
 
 
 def main():
+    from repro.launch.compile_info import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b_smoke")
     ap.add_argument("--requests", type=int, default=8)
@@ -515,8 +529,9 @@ def main():
                     help="serve from a mesh-sharded plan, e.g. 2x1 (data-"
                          "parallel fan-out) or 2x2 (+ tensor-parallel heads); "
                          "packed backends move uint32 spike words between "
-                         "devices, and a short fleet elastically degrades "
-                         "capacity instead of failing")
+                         "devices; a short fleet elastically degrades "
+                         "capacity, and a fleet that cannot hold one model "
+                         "group is an error")
     args = ap.parse_args()
     if args.vision:
         serve_vision(args.arch, num_requests=args.requests, slots=args.slots,

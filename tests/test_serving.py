@@ -472,6 +472,66 @@ def test_continuous_mesh_matches_single_device():
         ContinuousScheduler(plan, slots=3)
 
 
+def test_elastic_mesh_abort_raises():
+    """Regression: a mesh whose model axis cannot fit the fleet used to fall
+    back to single-device serving; it must raise instead, on every serve
+    path, before any plan is compiled."""
+    too_wide = (1, jax.device_count() + 1)
+    with pytest.raises(RuntimeError, match="infeasible"):
+        serve_mod._elastic_mesh(too_wide, 4, verbose=False)
+    with pytest.raises(RuntimeError, match="infeasible"):
+        serve_mod.serve_vision("spike-iand-former_smoke", num_requests=2,
+                               slots=2, mesh=too_wide, verbose=False)
+    with pytest.raises(RuntimeError, match="infeasible"):
+        serve_mod.serve_spiking_lm_continuous(
+            "llama3.2-1b_smoke", num_requests=1, prompt_len=4, max_new=2,
+            mesh=too_wide, verbose=False)
+
+
+def test_serve_vision_mesh_places_weights():
+    """Mesh-sharded vision serving commits the plan's block weights across
+    the mesh (no per-call weight transfer from device 0) and returns logits
+    bit-equal to single-device serving."""
+    _skip_under(2)
+    kw = dict(num_requests=4, slots=2, backend="jnp+packed", verbose=False,
+              return_stats=True)
+    _, single = serve_mod.serve_vision("spike-iand-former_smoke", **kw)
+    _, meshed = serve_mod.serve_vision("spike-iand-former_smoke",
+                                       mesh="1x2", **kw)
+    np.testing.assert_array_equal(meshed["logits"], single["logits"])
+    assert meshed["logits"].shape == (4, 10)
+    w = meshed["plan"].params["blocks"][0]["q"]["w"]
+    assert len(w.sharding.device_set) == 2
+    assert not w.sharding.is_fully_replicated
+
+
+@pytest.mark.parametrize("env_dir", [None, "custom"])
+def test_enable_compile_cache(monkeypatch, tmp_path, env_dir):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says (and nothing is set in code), else to the fixed in-checkout
+    ``.jax_cache`` directory."""
+    from pathlib import Path
+
+    from repro.launch.compile_info import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        got = enable_compile_cache()
+        if env_dir is None:
+            checkout = Path(serve_mod.__file__).resolve().parents[3]
+            assert got == str(checkout / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 # -- capacity accounting -------------------------------------------------------
 
 def test_decode_slot_report():
