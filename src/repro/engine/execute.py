@@ -181,22 +181,23 @@ def _tokenizer_exec(meta: PlanMeta, tok_params, image):
     """image: (B, H, W, C) analog in [0, 1] -> spikes (T, B, N, D)."""
     cfg = meta.cfg
     x = None
-    for stage, p in zip(meta.tok_stages, tok_params):
-        if stage.encode:
-            # encoding layer: analog conv once, broadcast across T (the input
-            # is not binary, so it stays on the jnp conv even under the
-            # spike-GEMM backend)
-            y = cnn.conv_apply(p, image)
-            if stage.pool:
-                y = cnn.maxpool(y)
-            drive = jnp.broadcast_to(y[None], (cfg.t,) + y.shape)
-        else:
-            flat = cnn.fold_time(x)          # (T*B, H, W, C): one weight read
-            y = B.conv3x3_apply(meta.backend, p, flat)
-            if stage.pool:
-                y = cnn.maxpool(y)
-            drive = cnn.unfold_time(y, cfg.t)
-        x = _lif(meta, drive)
+    for i, (stage, p) in enumerate(zip(meta.tok_stages, tok_params)):
+        with jax.named_scope(f"stage{i}"):
+            if stage.encode:
+                # encoding layer: analog conv once, broadcast across T (the
+                # input is not binary, so it stays on the jnp conv even under
+                # the spike-GEMM backend)
+                y = cnn.conv_apply(p, image)
+                if stage.pool:
+                    y = cnn.maxpool(y)
+                drive = jnp.broadcast_to(y[None], (cfg.t,) + y.shape)
+            else:
+                flat = cnn.fold_time(x)      # (T*B, H, W, C): one weight read
+                y = B.conv3x3_apply(meta.backend, p, flat)
+                if stage.pool:
+                    y = cnn.maxpool(y)
+                drive = cnn.unfold_time(y, cfg.t)
+            x = _lif(meta, drive)
     t, b, h, w, d = x.shape
     return x.reshape(t, b, h * w, d)
 
@@ -220,34 +221,38 @@ def _block_exec(meta: PlanMeta, bparams, x, *, ops: _MeshOps = _NULL_OPS,
     acts: dict = {}
     h = None
     for u in meta.block_units:
-        if u.role == "qkv":
-            if xg is None:
-                xg = ops.gather_stream(x)
-            acts[u.name] = _lif(meta, _unit_linear(meta, bparams[u.name], xg))
-            continue
         if u.role == "attn_out":
             heads = ops.local_heads(cfg.num_heads)
-            attn = B.ssa_apply(
-                meta.backend,
-                split_heads(acts["q"], heads),
-                split_heads(acts["k"], heads),
-                split_heads(acts["v"], heads),
-                scale=cfg.attn_scale, ordering=cfg.attn_ordering)
-            attn = _lif(meta, merge_heads(attn))          # attn spikes
-            drive = _unit_linear(meta, bparams[u.name], ops.gather_heads(attn))
-        elif u.role == "mlp_hidden":
-            if xg is None:
-                xg = ops.gather_stream(x)
-            h = _lif(meta, _unit_linear(meta, bparams[u.name], xg))
-            continue
-        elif u.role == "mlp_out":
-            drive = _unit_linear(meta, bparams[u.name], ops.gather_heads(h))
-        else:
-            raise ValueError(f"unknown unit role: {u.role}")
-        if u.fuse_residual:      # AND-NOT inside the LIF epilogue
-            x = _lif(meta, drive, iand_skip=x)
-        else:
-            x = res(x, _lif(meta, drive))
+            with jax.named_scope("ssa"):
+                attn = B.ssa_apply(
+                    meta.backend,
+                    split_heads(acts["q"], heads),
+                    split_heads(acts["k"], heads),
+                    split_heads(acts["v"], heads),
+                    scale=cfg.attn_scale, ordering=cfg.attn_ordering)
+            with jax.named_scope("attn_lif"):
+                attn = _lif(meta, merge_heads(attn))      # attn spikes
+        with jax.named_scope(u.name):
+            if u.role == "qkv":
+                if xg is None:
+                    xg = ops.gather_stream(x)
+                acts[u.name] = _lif(meta, _unit_linear(meta, bparams[u.name], xg))
+                continue
+            if u.role == "attn_out":
+                drive = _unit_linear(meta, bparams[u.name], ops.gather_heads(attn))
+            elif u.role == "mlp_hidden":
+                if xg is None:
+                    xg = ops.gather_stream(x)
+                h = _lif(meta, _unit_linear(meta, bparams[u.name], xg))
+                continue
+            elif u.role == "mlp_out":
+                drive = _unit_linear(meta, bparams[u.name], ops.gather_heads(h))
+            else:
+                raise ValueError(f"unknown unit role: {u.role}")
+            if u.fuse_residual:      # AND-NOT inside the LIF epilogue
+                x = _lif(meta, drive, iand_skip=x)
+            else:
+                x = res(x, _lif(meta, drive))
         xg = None                # the residual stream advanced: stale gather
     return x
 
@@ -258,18 +263,20 @@ def _tokenizer_exec_packed(meta: PlanMeta, tok_params, image) -> packing.PackedS
     """image: (B, H, W, C) analog -> packed spikes, words (W, B, N, D)."""
     cfg = meta.cfg
     xp = None
-    for stage, p in zip(meta.tok_stages, tok_params):
-        if stage.encode:
-            # analog encoding conv: same as the dense path (input not binary)
-            y = cnn.conv_apply(p, image)
-            if stage.pool:
-                y = cnn.maxpool(y)
-            drive = jnp.broadcast_to(y[None], (cfg.t,) + y.shape)
-        else:
-            drive = B.conv3x3_apply_packed(meta.backend, p, xp)  # (T,B,H,W,C)
-            if stage.pool:
-                drive = cnn.unfold_time(cnn.maxpool(cnn.fold_time(drive)), cfg.t)
-        xp = _lif(meta, drive, pack_output=True)
+    for i, (stage, p) in enumerate(zip(meta.tok_stages, tok_params)):
+        with jax.named_scope(f"stage{i}"):
+            if stage.encode:
+                # analog encoding conv: same as the dense path (input not binary)
+                y = cnn.conv_apply(p, image)
+                if stage.pool:
+                    y = cnn.maxpool(y)
+                drive = jnp.broadcast_to(y[None], (cfg.t,) + y.shape)
+            else:
+                drive = B.conv3x3_apply_packed(meta.backend, p, xp)  # (T,B,H,W,C)
+                if stage.pool:
+                    drive = cnn.unfold_time(cnn.maxpool(cnn.fold_time(drive)),
+                                            cfg.t)
+            xp = _lif(meta, drive, pack_output=True)
     w, b, h, wd, d = xp.words.shape
     return xp.reshape_elems(b, h * wd, d)
 
@@ -290,39 +297,43 @@ def _block_exec_packed(meta: PlanMeta, bparams, xp: packing.PackedSpikes, *,
     acts: dict = {}
     h = None
     for u in meta.block_units:
-        if u.role == "qkv":
-            if xg is None:
-                xg = ops.gather_stream(xp)
-            acts[u.name] = _lif(
-                meta, _unit_linear_packed(meta, bparams[u.name], xg),
-                pack_output=True)
-            continue
         if u.role == "attn_out":
             # q/k/v stay packed through the head split; the backend feeds the
             # words straight to the packed SSA kernel (or unpacks at ITS op
             # boundary on the oracle route -- never here)
             heads = ops.local_heads(cfg.num_heads)
-            attn = B.ssa_apply_packed(
-                meta.backend,
-                split_heads_packed(acts["q"], heads),
-                split_heads_packed(acts["k"], heads),
-                split_heads_packed(acts["v"], heads),
-                scale=cfg.attn_scale, ordering=cfg.attn_ordering)
-            attn_sp = _lif(meta, merge_heads(attn), pack_output=True)
-            drive = _unit_linear_packed(meta, bparams[u.name],
-                                        ops.gather_heads(attn_sp))
-        elif u.role == "mlp_hidden":
-            if xg is None:
-                xg = ops.gather_stream(xp)
-            h = _lif(meta, _unit_linear_packed(meta, bparams[u.name], xg),
-                     pack_output=True)
-            continue
-        elif u.role == "mlp_out":
-            drive = _unit_linear_packed(meta, bparams[u.name],
-                                        ops.gather_heads(h))
-        else:
-            raise ValueError(f"unknown unit role: {u.role}")
-        xp = _lif(meta, drive, iand_skip=xp, pack_output=True)
+            with jax.named_scope("ssa"):
+                attn = B.ssa_apply_packed(
+                    meta.backend,
+                    split_heads_packed(acts["q"], heads),
+                    split_heads_packed(acts["k"], heads),
+                    split_heads_packed(acts["v"], heads),
+                    scale=cfg.attn_scale, ordering=cfg.attn_ordering)
+            with jax.named_scope("attn_lif"):
+                attn_sp = _lif(meta, merge_heads(attn), pack_output=True)
+        with jax.named_scope(u.name):
+            if u.role == "qkv":
+                if xg is None:
+                    xg = ops.gather_stream(xp)
+                acts[u.name] = _lif(
+                    meta, _unit_linear_packed(meta, bparams[u.name], xg),
+                    pack_output=True)
+                continue
+            if u.role == "attn_out":
+                drive = _unit_linear_packed(meta, bparams[u.name],
+                                            ops.gather_heads(attn_sp))
+            elif u.role == "mlp_hidden":
+                if xg is None:
+                    xg = ops.gather_stream(xp)
+                h = _lif(meta, _unit_linear_packed(meta, bparams[u.name], xg),
+                         pack_output=True)
+                continue
+            elif u.role == "mlp_out":
+                drive = _unit_linear_packed(meta, bparams[u.name],
+                                            ops.gather_heads(h))
+            else:
+                raise ValueError(f"unknown unit role: {u.role}")
+            xp = _lif(meta, drive, iand_skip=xp, pack_output=True)
         xg = None                # the residual stream advanced: stale gather
     return xp
 
@@ -457,24 +468,29 @@ def _execute(meta: PlanMeta, params, batch, *, ops: _MeshOps = _NULL_OPS):
         tokens = batch["tokens"] if isinstance(batch, dict) else batch
         return _lm_exec(meta, params, tokens, packed=meta.backend.packed,
                         ops=ops)
-    if meta.backend.packed:
-        xg = _tokenizer_exec_packed(meta, params["tokenizer"], batch)
-        xp = ops.shard_stream(xg)       # land on the feature-sharded stream
-        for bparams in params["blocks"]:
-            # the replicated tokenizer output doubles as the first block's
-            # gathered view -- the tokenizer edge never crosses devices
-            xp = _block_exec_packed(meta, bparams, xp, ops=ops, xg=xg)
-            xg = None
-        xp = ops.gather_stream(xp)      # replicated head reads the full row
-        return _head_packed(meta, params["head"], xp)
-    xg = _tokenizer_exec(meta, params["tokenizer"], batch)
-    x = ops.shard_stream(xg)
-    for bparams in params["blocks"]:
-        x = _block_exec(meta, bparams, x, ops=ops, xg=xg)
+    # every op runs under a named scope of the plan's layout
+    # (``tokenizer/stage{i}``, ``block{i}/{unit}`` with ``block{i}/ssa`` and
+    # ``block{i}/attn_lif``, ``head``), kept as the compiled HLO's
+    # ``op_name`` metadata, so a device trace's operations map back to the
+    # layer they serve.  Metadata only: scopes change no instruction.
+    packed = meta.backend.packed
+    tokenizer = _tokenizer_exec_packed if packed else _tokenizer_exec
+    block = _block_exec_packed if packed else _block_exec
+    with jax.named_scope("tokenizer"):
+        xg = tokenizer(meta, params["tokenizer"], batch)
+    x = ops.shard_stream(xg)            # land on the feature-sharded stream
+    for i, bparams in enumerate(params["blocks"]):
+        # the replicated tokenizer output doubles as the first block's
+        # gathered view -- the tokenizer edge never crosses devices
+        with jax.named_scope(f"block{i}"):
+            x = block(meta, bparams, x, ops=ops, xg=xg)
         xg = None
-    x = ops.gather_stream(x)
-    feats = x.mean(axis=(0, 2))              # rate decoding over (T, tokens)
-    return cnn.linear_apply(params["head"], feats)
+    with jax.named_scope("head"):
+        x = ops.gather_stream(x)        # replicated head reads the full row
+        if packed:
+            return _head_packed(meta, params["head"], x)
+        feats = x.mean(axis=(0, 2))          # rate decoding over (T, tokens)
+        return cnn.linear_apply(params["head"], feats)
 
 
 # -- incremental LM decode ----------------------------------------------------

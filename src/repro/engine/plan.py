@@ -20,6 +20,11 @@ residual into their epilogue (execution never runs a standalone IAND pass)
 and the backend (jnp oracle vs Pallas kernels, interpret vs compiled, packed
 spikes) is a plan property, not a per-call-site flag.
 
+Each ``compile_plan`` call runs inside the profiler span
+``engine.compile_plan`` and reports its host wall time as the JAX monitoring
+duration event :data:`FOLD_EVENT`, so a start-up profile or an event listener
+sees the fold apart from compilation and warm-up.
+
 The plan splits into hashable static metadata (:class:`PlanMeta`) and a plain
 pytree of folded arrays, so executors jit cleanly with the metadata closed
 over and the arrays as arguments.
@@ -27,6 +32,8 @@ over and the arrays as arguments.
 
 from __future__ import annotations
 
+import functools
+import time
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -287,6 +294,28 @@ class DeployPlan:
         return self.meta.backend
 
 
+FOLD_EVENT = "/repro/engine/compile_plan"
+
+
+def _timed_fold(fold):
+    """Run ``fold`` inside the ``engine.compile_plan`` span and record its
+    host wall time as :data:`FOLD_EVENT`.  The fold's eager ops dispatch
+    asynchronously: device work still pending when it returns lands in
+    whatever next waits on the plan."""
+
+    @functools.wraps(fold)
+    def timed(*args, **kwargs):
+        start = time.perf_counter()
+        with jax.profiler.TraceAnnotation("engine.compile_plan"):
+            plan = fold(*args, **kwargs)
+        jax.monitoring.record_event_duration_secs(
+            FOLD_EVENT, time.perf_counter() - start)
+        return plan
+
+    return timed
+
+
+@_timed_fold
 def compile_plan(params, state, cfg, *, backend="jnp",
                  ordering: str | None = None, checkpoint: str | None = None,
                  bundle: float | None = None, mesh=None) -> DeployPlan:

@@ -266,6 +266,66 @@ def test_plan_stats(tiny_trained):
     assert add_stats["standalone_add_ops"] == 2 * cfg.num_layers
 
 
+# -- layer names in the compiled executor -------------------------------------
+
+def _hlo_scopes(hlo_text):
+    """The named-scope paths of a compiled module's ``op_name`` metadata,
+    without the ``jit(...)`` wrappers and the primitive at each path's end."""
+    import re
+
+    out = set()
+    for name in re.findall(r'op_name="([^"]*)"', hlo_text):
+        parts = [p for p in name.split("/") if "(" not in p]
+        if name.startswith("jit(") and len(parts) > 1:
+            out.add("/".join(parts[:-1]))
+    return out
+
+
+@pytest.mark.parametrize("backend", ["jnp", "jnp+packed"])
+def test_executor_names_every_layer(tiny_trained, backend):
+    """Each tokenizer stage, block unit (with the SSA and attention LIF) and
+    the head runs under its named scope, in both vision walkers, taken from
+    the plan's own layout."""
+    params, state, img = tiny_trained
+    plan = engine.compile_plan(params, state, _tiny(), backend=backend)
+    meta = plan.meta
+    assert len(meta.tok_stages) == 4
+    units = [u.name for u in meta.block_units]
+    assert units == ["q", "k", "v", "proj", "fc1", "fc2"]
+    want = ({f"tokenizer/stage{i}" for i in range(4)} | {"head"}
+            | {f"block{b}/{u}" for b in range(meta.num_layers)
+               for u in units + ["ssa", "attn_lif"]})
+    hlo = jax.jit(engine.make_apply_fn(plan)).lower(plan.params, img).compile().as_text()
+    got = _hlo_scopes(hlo)
+    assert want <= got
+    assert {s.split("/")[0] for s in got} == {"tokenizer", "head"} | {
+        f"block{b}" for b in range(meta.num_layers)}
+
+
+def test_compile_plan_reports_its_duration(tiny_trained):
+    """One ``/repro/engine/compile_plan`` duration event per fold, whatever
+    the family or backend."""
+    from repro.engine.plan import FOLD_EVENT
+
+    params, state, _ = tiny_trained
+    seen = []
+
+    def listen(event, secs, **_):
+        if event == FOLD_EVENT:
+            seen.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        engine.compile_plan(params, state, _tiny())
+        assert len(seen) == 1
+        engine.compile_plan(params, state, _tiny(), backend="jnp+packed")
+        assert len(seen) == 2
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert all(s > 0 for s in seen)
+    assert FOLD_EVENT == "/repro/engine/compile_plan"
+
+
 def test_backend_resolution():
     assert engine.resolve_backend(None) == engine.JNP
     assert engine.resolve_backend(True) == engine.PALLAS

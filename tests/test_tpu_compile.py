@@ -114,3 +114,53 @@ def test_kernel_compiles_for_v5e(one_chip, name, t):
     fn, args = _case(name, t, one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_executor_layers_named_for_v5e(one_chip):
+    """The whole ``pallas+packed`` executor of Spike-IAND-Former 8-384 at
+    batch 1 (the edge cell's) compiles for the described chip with every
+    instruction in a layer of the plan: each of the 119 Pallas kernels has
+    its named scope, each block's SSA scope holds its one SSA kernel and each
+    unit scope its spike GEMM.  The table is the benchmark's own
+    (``bench/benchlib/scopes.py``), which maps a device trace onto layers."""
+    import fnmatch
+    import re
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    from benchlib import cell, scopes, spec
+
+    from repro import engine
+    from repro.engine.plan import DeployPlan
+
+    cfg = spec.load_config("sif-8-384")
+    shapes, meta = cell.abstract_plan(cfg)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), shapes)
+    img = _spec(one_chip, (1, cfg["img_size"], cfg["img_size"], cfg["in_channels"]),
+                jnp.float32)
+    fn = jax.jit(engine.make_apply_fn(DeployPlan(meta=meta, params=shapes)))
+    hlo = fn.lower(params, img).compile().as_text()
+    table = scopes.op_scopes(hlo)
+
+    units = ("q", "k", "v", "ssa", "attn_lif", "proj", "fc1", "fc2")
+    layout = ({f"tokenizer/stage{i}" for i in range(4)} | {"head"}
+              | {f"block{b}/{u}" for b in range(cfg["num_layers"]) for u in units})
+    assert {scope for scope, _ in table.values()} - {None} == layout
+    kernels = {name: scope for name, (scope, is_kernel) in table.items() if is_kernel}
+    assert len(kernels) == hlo.count("tpu_custom_call") == 119
+    assert all(kernels.values())
+    for b in range(cfg["num_layers"]):
+        ssa = [n for n, s in kernels.items() if s == f"block{b}/ssa"]
+        assert len(ssa) == 1 and fnmatch.fnmatchcase(ssa[0], "*ssa*"), ssa
+        for u in ("q", "k", "v", "proj", "fc1", "fc2"):
+            assert any(fnmatch.fnmatchcase(n, "*spike_matmul*")
+                       for n, s in kernels.items() if s == f"block{b}/{u}"), (b, u)
+    exempt = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast"}
+    opcodes = dict(re.findall(
+        r"(?m)^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?\s([a-z][\w\-]*)\(", hlo))
+    loose = [n for n, (s, _) in table.items() if s is None and opcodes.get(n) not in exempt]
+    assert not loose, loose[:20]
