@@ -7,7 +7,7 @@ The tentpole claim of the bit-packed deploy engine, measured two ways:
   bit-packed uint32 words via ``engine.analysis.spike_traffic``.  At T=8 the
   packed datapath moves 1/8 the spike-activation bytes (1/32 at T=32) --
   the acceptance bar is >= 8x at T=8.  Priced under the packed Pallas
-  backend, whose ``packed_ssa_op`` kernel consumes the q/k/v words directly
+  backend, whose ``packed_ssa_merged_op`` kernel consumes the q/k/v words directly
   (``closes_ssa_boundary``), the SSA-boundary column EQUALS the full packed
   contract: 8x at T=8, 32x at T=32, guaranteed on every edge.  The open
   column (jnp oracle backend, operands unpacked at the attention boundary)
@@ -121,7 +121,7 @@ def main():
     print("packed_traffic: inter-layer spike-activation bytes, "
           "dense f32 vs bit-packed uint32 words (per image; 'ssa closed' "
           "prices the q/k/v edges under the packed Pallas backend, whose "
-          "packed_ssa_op kernel consumes the words directly; 'ssa open' is "
+          "packed_ssa_merged_op kernel consumes the words directly; 'ssa open' is "
           "the conservative jnp-oracle number, operands unpacked at the "
           "attention boundary)")
     print(f"{'config':10s} {'T':>3s} {'dense MB':>10s} {'packed MB':>10s} "

@@ -50,7 +50,7 @@ def write_bench_json(engine_result, packed_result, lm_result=None,
     tokens/s and inter-layer activation bytes, tracked across PRs.
 
     ``packed_reduction_ssa_dense`` prices the q/k/v attention edges under the
-    packed Pallas deploy backend in EVERY row: its ``packed_ssa_op`` kernel
+    packed Pallas deploy backend in EVERY row: its ``packed_ssa_merged_op`` kernel
     consumes the words directly (``ssa_boundary_closed`` True), so the column
     equals ``packed_reduction`` -- the full 8x/32x contract.
     ``packed_reduction_ssa_open`` is the uniform companion column pricing

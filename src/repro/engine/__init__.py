@@ -31,10 +31,11 @@ bit-exact vs the single-device plan on every backend and ordering.
 from repro.engine.backend import (
     JNP, JNP_PACKED, PALLAS, PALLAS_PACKED, Backend,
     resolve as resolve_backend, spike_allgather, spike_shard, ssa_apply,
-    ssa_apply_packed, ssa_decode_step, ssa_decode_step_packed,
-    ssa_prefill_apply, ssa_prefill_apply_packed, ssa_prefill_chunk,
-    ssa_prefill_chunk_packed, ssa_prefill_state, ssa_prefill_state_packed,
-    unit_partition_specs, word_allgather, word_psum, word_reduce_scatter,
+    ssa_apply_packed, ssa_apply_packed_merged, ssa_decode_step,
+    ssa_decode_step_packed, ssa_prefill_apply, ssa_prefill_apply_packed,
+    ssa_prefill_chunk, ssa_prefill_chunk_packed, ssa_prefill_state,
+    ssa_prefill_state_packed, unit_partition_specs, word_allgather, word_psum,
+    word_reduce_scatter,
 )
 from repro.engine.execute import (
     DecodeState, apply, decode_state_batch_init, decode_state_gather,
@@ -54,7 +55,8 @@ from repro.engine.plan import (
 __all__ = [
     "JNP", "JNP_PACKED", "PALLAS", "PALLAS_PACKED", "Backend",
     "resolve_backend", "spike_allgather", "spike_shard", "ssa_apply",
-    "ssa_apply_packed", "ssa_decode_step", "ssa_decode_step_packed",
+    "ssa_apply_packed", "ssa_apply_packed_merged", "ssa_decode_step",
+    "ssa_decode_step_packed",
     "ssa_prefill_apply", "ssa_prefill_apply_packed", "ssa_prefill_chunk",
     "ssa_prefill_chunk_packed", "ssa_prefill_state",
     "ssa_prefill_state_packed", "unit_partition_specs", "word_allgather",

@@ -288,7 +288,8 @@ def _boundary_closed(backend, ordering: str) -> bool:
     if backend is None:
         return False
     # both orderings close under the packed kernel route: quadratic through
-    # ``packed_ssa_op``, linear through the in-register shift-and-mask scans
+    # ``packed_ssa_merged_op`` (vision) / ``packed_ssa_op`` (LM), linear
+    # through the in-register shift-and-mask scans
     # (``ssa_linear_packed`` / ``ssa_causal_linear_with_state_packed``)
     return (resolve(backend).closes_ssa_boundary
             and ordering in ("quadratic", "linear"))

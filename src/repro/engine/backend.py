@@ -27,7 +27,10 @@ bitplane words consumed directly by ``packed_ssa_op`` when
 ``Backend.closes_ssa_boundary``; unpacked at the op boundary otherwise), and
 the incremental-decode ops :func:`ssa_decode_step` / :func:`ssa_prefill_state`
 and their ``_packed`` variants (words consumed in-register under the closed
-boundary, so the packed datapath survives decode).  The executor never calls
+boundary, so the packed datapath survives decode).  The packed vision walker
+calls :func:`ssa_apply_packed_merged` on token-major words, which takes the
+head-merged packed SSA kernel where the boundary closes and splits heads
+around :func:`ssa_apply_packed` everywhere else.  The executor never calls
 a kernel or an oracle directly, so a plan's kernel route is a property of its
 Backend, with no silent exemptions.
 """
@@ -352,6 +355,42 @@ def ssa_apply_packed(backend: Backend, qp: packing.PackedSpikes,
     q, k, v = (packing.unpack(p) for p in (qp, kp, vp))
     return ssa_apply(backend, q, k, v, scale=scale, ordering=ordering,
                      causal=causal)
+
+
+# JAX monitoring events, one per traced vision block, naming the SSA route
+SSA_ROUTE_MERGED = "/repro/engine/ssa_route/head_merged"
+SSA_ROUTE_SPLIT = "/repro/engine/ssa_route/head_split"
+
+
+def ssa_apply_packed_merged(backend: Backend, qp: packing.PackedSpikes,
+                            kp: packing.PackedSpikes, vp: packing.PackedSpikes,
+                            *, heads: int, scale: float,
+                            ordering: str = "quadratic") -> jax.Array:
+    """Spiking self-attention on token-major packed q/k/v trains (words
+    (W, B, N, D), ``heads`` heads as lane slices of D) -> dense drive
+    (T, B, N, D), the layout the attention LIF consumes.
+
+    On the compiled Pallas route (``closes_ssa_boundary``, quadratic
+    ordering, not sparse) the words feed ``packed_ssa_merged_op`` as they
+    are: no head split, merge or pad.  Every other route splits the heads,
+    runs :func:`ssa_apply_packed` and merges them back.  Each call records
+    :data:`SSA_ROUTE_MERGED` or :data:`SSA_ROUTE_SPLIT` as a JAX monitoring
+    event (at trace time under ``jit``).
+    """
+    if (ordering == "quadratic" and backend.closes_ssa_boundary
+            and not backend.sparse):
+        from repro.kernels.spiking_attention.ops import packed_ssa_merged_op
+
+        jax.monitoring.record_event(SSA_ROUTE_MERGED)
+        return packed_ssa_merged_op(qp.words, kp.words, vp.words, t=qp.t,
+                                    heads=heads, scale=scale,
+                                    interpret=backend.interpret)
+    from repro.core.spiking_attention import merge_heads, split_heads_packed
+
+    jax.monitoring.record_event(SSA_ROUTE_SPLIT)
+    q, k, v = (split_heads_packed(p, heads) for p in (qp, kp, vp))
+    return merge_heads(ssa_apply_packed(backend, q, k, v, scale=scale,
+                                        ordering=ordering))
 
 
 def ssa_decode_step(backend: Backend, state: jax.Array, q: jax.Array,

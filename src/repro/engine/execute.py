@@ -298,19 +298,16 @@ def _block_exec_packed(meta: PlanMeta, bparams, xp: packing.PackedSpikes, *,
     h = None
     for u in meta.block_units:
         if u.role == "attn_out":
-            # q/k/v stay packed through the head split; the backend feeds the
-            # words straight to the packed SSA kernel (or unpacks at ITS op
-            # boundary on the oracle route -- never here)
-            heads = ops.local_heads(cfg.num_heads)
+            # q/k/v stay packed and token-major; the backend feeds the words
+            # straight to the head-merged SSA kernel (or splits heads and
+            # unpacks at ITS op boundary on the other routes -- never here)
             with jax.named_scope("ssa"):
-                attn = B.ssa_apply_packed(
-                    meta.backend,
-                    split_heads_packed(acts["q"], heads),
-                    split_heads_packed(acts["k"], heads),
-                    split_heads_packed(acts["v"], heads),
+                attn = B.ssa_apply_packed_merged(
+                    meta.backend, acts["q"], acts["k"], acts["v"],
+                    heads=ops.local_heads(cfg.num_heads),
                     scale=cfg.attn_scale, ordering=cfg.attn_ordering)
             with jax.named_scope("attn_lif"):
-                attn_sp = _lif(meta, merge_heads(attn), pack_output=True)
+                attn_sp = _lif(meta, attn, pack_output=True)
         with jax.named_scope(u.name):
             if u.role == "qkv":
                 if xg is None:

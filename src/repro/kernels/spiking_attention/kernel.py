@@ -18,6 +18,11 @@ VMEM per program ~= block_q*D + 2*M*D + block_q*M floats.
 bitplane words (G = B*H, time lives in the bits), each bitplane is unpacked
 per-tile in VMEM, and the output is the dense (T, G, N, D) drive -- spikes
 never materialise dense outside VMEM on the operand side.
+
+``packed_ssa_merged_fwd`` is the vision layout of the same product: q/k/v stay
+token-major (W, B, N, D) with heads as lane slices of D, one program per image
+and lane group of whole heads, all N tokens at once -- no head split or merge
+around the kernel and no padding of the head dim.
 """
 
 from __future__ import annotations
@@ -194,5 +199,70 @@ def packed_ssa_fwd(qw: jax.Array, kw: jax.Array, vw: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((t_total, 1, bq, d), lambda gi, qi: (0, gi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((t_total, g, n, d), jnp.float32),
+        interpret=interpret,
+    )(qw, kw, vw)
+
+
+def head_group(d: int, dh: int) -> int:
+    """Lanes per program of the head-merged SSA: the fewest that hold whole
+    heads and form a legal block -- a multiple of 128 lanes or the whole
+    width ``d`` (128 itself whenever ``dh`` divides 128 and 128 divides ``d``)."""
+    for g in range(dh, d + 1, dh):
+        if d % g == 0 and (g % 128 == 0 or g == d):
+            return g
+    raise ValueError(f"head dim {dh} does not divide width {d}")
+
+
+def packed_ssa_merged_kernel(qw_ref, kw_ref, vw_ref, o_ref, *, t_total: int,
+                             dh: int, scale: float):
+    """Head-merged packed SSA over one image and one lane group of whole heads.
+
+    Each bitplane of q, k and v is unpacked once per program.  Head ``j``'s
+    scores contract q masked to its lanes against all of k (the other heads'
+    lanes add zeros), and its output is scores times v masked to its lanes,
+    so each head writes only its own lanes of the (N, g) tile.  Spikes are
+    0/1 and a head's score counts at most ``dh`` lanes, so both MXU operands
+    are exact in bfloat16 for ``dh <= 256``; every sum is an integer below
+    2^24 in float32 and ``scale`` is a power of two: the drive is bit-exact.
+    """
+    g = qw_ref.shape[-1]
+    lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, g), 1) // dh
+    dt = jnp.bfloat16 if dh <= 256 else jnp.float32
+    for t in range(t_total):
+        wi, bit = divmod(t, 32)
+        q = bitplane(qw_ref[wi, 0], bit)
+        k = bitplane(kw_ref[wi, 0], bit).astype(dt)
+        v = bitplane(vw_ref[wi, 0], bit)
+        out = None
+        for j in range(g // dh):
+            in_head = lane_head == j
+            qj = jnp.where(in_head, q, 0.0).astype(dt)
+            vj = jnp.where(in_head, v, 0.0).astype(dt)
+            scores = jnp.dot(qj, k.T, preferred_element_type=jnp.float32)
+            part = jnp.dot(scores.astype(dt), vj,
+                           preferred_element_type=jnp.float32)
+            out = part if out is None else out + part
+        o_ref[t, 0] = out * scale
+
+
+def packed_ssa_merged_fwd(qw: jax.Array, kw: jax.Array, vw: jax.Array, *,
+                          t_total: int, heads: int, scale: float,
+                          interpret: bool) -> jax.Array:
+    """qw/kw/vw (W, B, N, D) token-major uint32 spike words, ``heads`` heads
+    as lane slices of D -> (T, B, N, D) f32 drive.  Grid (B, D / g), blocks
+    (W, 1, N, g) in and (T, 1, N, g) out: the token block is the whole N."""
+    w, b, n, d = qw.shape
+    if d % heads:
+        raise ValueError(f"width {d} does not split into {heads} heads")
+    dh = d // heads
+    g = head_group(d, dh)
+    words = pl.BlockSpec((w, 1, n, g), lambda bi, gi: (0, bi, 0, gi))
+    return pl.pallas_call(
+        functools.partial(packed_ssa_merged_kernel, t_total=t_total, dh=dh,
+                          scale=scale),
+        grid=(b, d // g),
+        in_specs=[words, words, words],
+        out_specs=pl.BlockSpec((t_total, 1, n, g), lambda bi, gi: (0, bi, 0, gi)),
+        out_shape=jax.ShapeDtypeStruct((t_total, b, n, d), jnp.float32),
         interpret=interpret,
     )(qw, kw, vw)

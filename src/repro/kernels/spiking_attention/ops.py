@@ -11,7 +11,9 @@ correct while the forward uses the kernel.
 bitplane words (``repro.core.packing`` layout, multi-word trains supported),
 so the attention operands stay packed end to end -- the kernel unpacks
 bitplanes per-tile in VMEM.  Inference-only (packed trains do not carry
-gradients).
+gradients).  ``packed_ssa_merged_op`` takes the same words token-major
+(W, B, N, D), heads as lane slices of D, and returns the drive in the
+(T, B, N, D) layout the attention LIF consumes: no head split, merge or pad.
 """
 
 from __future__ import annotations
@@ -110,6 +112,21 @@ def packed_ssa_op(qw: jax.Array, kw: jax.Array, vw: jax.Array, *, t: int,
                            interpret=resolve_interpret(interpret),
                            causal=causal)
     return out[:, :, :n, :d].reshape(t, b, h, n, dh)
+
+
+@functools.partial(jax.jit, static_argnames=("t", "heads", "scale", "interpret"))
+def packed_ssa_merged_op(qw: jax.Array, kw: jax.Array, vw: jax.Array, *,
+                         t: int, heads: int, scale: float = 0.125,
+                         interpret: bool | None = None) -> jax.Array:
+    """Head-merged packed SSA: qw/kw/vw (W, B, N, D) token-major uint32 spike
+    words with ``heads`` heads as lane slices of D -> dense drive
+    (T, B, N, D) f32, bit-identical to
+    ``merge_heads(packed_ssa_op(split_heads_packed(...)))``.  One kernel
+    program per image and lane group of whole heads (``kernel.head_group``),
+    all N tokens at once; non-causal (the vision SSA)."""
+    return K.packed_ssa_merged_fwd(qw, kw, vw, t_total=t, heads=heads,
+                                   scale=float(scale),
+                                   interpret=resolve_interpret(interpret))
 
 
 def _plane_liveness(qf, kf, vf, t: int) -> jax.Array:

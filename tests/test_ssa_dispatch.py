@@ -5,6 +5,9 @@ Covers the dispatch-gap fix and the packed-operand SSA kernel:
     (multi-word trains) and at a ragged token count,
   * ``ssa_op`` at a ragged N (65): the query block is padded to sublane
     alignment instead of launching unaligned,
+  * ``packed_ssa_merged_op`` (token-major words, heads as lane slices) bit-exact
+    vs the head-split kernel in its split/merge and vs the oracle, and the
+    packed vision walker's route events (``head_merged`` / ``head_split``),
   * engine plans route attention through ``backend.ssa_apply`` /
     ``ssa_apply_packed`` (regression: the executor used to call the jnp
     einsum directly, leaving the Pallas kernel dead code),
@@ -18,6 +21,9 @@ Covers the dispatch-gap fix and the packed-operand SSA kernel:
     reference after dropping the dead prefill compile.
 """
 
+import collections
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,7 +33,10 @@ from repro import engine
 from repro.core import packing
 from repro.core import spikformer as sf
 from repro.engine import analysis
-from repro.kernels.spiking_attention.ops import packed_ssa_op, ssa_op
+from repro.core.spiking_attention import (
+    merge_heads, split_heads, split_heads_packed)
+from repro.kernels.spiking_attention.ops import (
+    packed_ssa_merged_op, packed_ssa_op, ssa_op)
 from repro.kernels.spiking_attention.ref import ssa_ref
 
 KEY = jax.random.PRNGKey(0)
@@ -93,6 +102,49 @@ def test_packed_ssa_op_ragged_token_count():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+# -- head-merged packed SSA kernel (the packed vision walker's) --------------
+
+MERGED_WIDTHS = [(768, 12), (384, 12), (512, 4), (192, 3)]     # (D, H)
+MERGED_CASES = (  # (D, H, N, B, T): every width at T=4 on each N and B
+    [(d, h, n, b, 4) for d, h in MERGED_WIDTHS for n in (196, 7) for b in (1, 3)]
+    + [(d, h, 196, 1 + 2 * (i % 2), 1) for i, (d, h) in enumerate(MERGED_WIDTHS)]
+    + [(d, h, 7, 3 - 2 * (i % 2), 33) for i, (d, h) in enumerate(MERGED_WIDTHS)])
+
+
+@pytest.mark.parametrize("d,h,n,b,t", MERGED_CASES)
+def test_packed_ssa_merged_op_bit_exact(d, h, n, b, t):
+    """Token-major words in, (T, B, N, D) drive out: bit-identical to the
+    head-split kernel wrapped in the split/merge it replaces and to the dense
+    oracle, on the cells' widths (two and four heads per 128-lane group), one
+    head per group (Dh 128) and a tensor-parallel local width whose group is
+    the whole of D (192 = 3 heads of 64), with multi-word trains (T=33)."""
+    dh = d // h
+    key = jax.random.PRNGKey(d + 10 * n + 100 * b + t)
+    ks = jax.random.split(key, 4)
+    density = jax.random.uniform(ks[0], (), minval=0.1, maxval=0.6)
+    q, k, v = ((jax.random.uniform(kk, (t, b, n, d)) < density)
+               .astype(jnp.float32) for kk in ks[1:])
+    qp, kp, vp = (packing.pack(x) for x in (q, k, v))
+    got = packed_ssa_merged_op(qp.words, kp.words, vp.words, t=t, heads=h)
+    assert got.shape == (t, b, n, d) and got.dtype == jnp.float32
+    split = merge_heads(packed_ssa_op(
+        *(split_heads_packed(x, h).words for x in (qp, kp, vp)), t=t))
+    fold = lambda x: split_heads(x, h).reshape(t * b * h, n, dh)
+    oracle = merge_heads(
+        ssa_ref(fold(q), fold(k), fold(v)).reshape(t, b, h, n, dh))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(split))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(oracle))
+
+
+@pytest.mark.parametrize("d,dh,g", [(768, 64, 128), (384, 32, 128),
+                                    (512, 128, 128), (192, 64, 192),
+                                    (768, 96, 384), (64, 16, 64), (96, 32, 96)])
+def test_head_group_holds_whole_heads_in_a_legal_block(d, dh, g):
+    from repro.kernels.spiking_attention.kernel import head_group
+
+    assert head_group(d, dh) == g
+
+
 # -- backend ssa_apply routing ------------------------------------------------
 
 TABLE1_HEAD_SHAPES = [  # (H, Dh) of the Table-I configs, N = 64 tokens
@@ -142,22 +194,95 @@ def test_engine_routes_attention_through_ssa_kernel(tiny_model, monkeypatch):
     assert calls["n"] == 0
 
 
-def test_engine_routes_packed_attention_through_packed_kernel(tiny_model, monkeypatch):
+def _count_calls(monkeypatch, *names):
+    """Count calls of the named spiking_attention ops (at trace time under
+    jit), still running the real op."""
     import repro.kernels.spiking_attention.ops as aops
 
+    calls = dict.fromkeys(names, 0)
+    for name in names:
+        def counting(*a, _name=name, _orig=getattr(aops, name), **kw):
+            calls[_name] += 1
+            return _orig(*a, **kw)
+
+        monkeypatch.setattr(aops, name, counting)
+    return calls
+
+
+@contextlib.contextmanager
+def _ssa_route_events():
+    """The SSA route events recorded inside the block, by route."""
+    seen = collections.Counter()
+    routes = {engine.backend.SSA_ROUTE_MERGED: "head_merged",
+              engine.backend.SSA_ROUTE_SPLIT: "head_split"}
+
+    def listen(event, **_):
+        if event in routes:
+            seen[routes[event]] += 1
+
+    jax.monitoring.register_event_listener(listen)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_listener(listen)
+
+
+def test_engine_routes_packed_attention_through_packed_kernel(tiny_model, monkeypatch):
+    """The packed vision walker hands token-major words to the head-merged
+    kernel, once per block; the head-split kernel is not called."""
     params, state, img = tiny_model
     cfg = _tiny()
-    calls = {"n": 0}
-    orig = aops.packed_ssa_op
-
-    def counting(*a, **kw):
-        calls["n"] += 1
-        return orig(*a, **kw)
-
-    monkeypatch.setattr(aops, "packed_ssa_op", counting)
+    calls = _count_calls(monkeypatch, "packed_ssa_op", "packed_ssa_merged_op")
     plan = engine.compile_plan(params, state, cfg, backend=PALLAS_PACKED_KERNEL)
-    engine.apply(plan, img)
-    assert calls["n"] == cfg.num_layers
+    with _ssa_route_events() as seen:
+        engine.apply(plan, img)
+    assert calls == {"packed_ssa_op": 0, "packed_ssa_merged_op": cfg.num_layers}
+    assert seen == {"head_merged": cfg.num_layers}
+
+
+@pytest.mark.parametrize("backend,route", [
+    (PALLAS_PACKED_KERNEL, "head_merged"),
+    ("jnp+packed", "head_split"),
+    (engine.Backend("pallas", matmul_kernel=True, packed=True, sparse=True),
+     "head_split"),
+], ids=["pallas+packed", "jnp+packed", "pallas+packed+sparse"])
+def test_packed_vision_plan_ssa_route(backend, route, monkeypatch):
+    """Spike-IAND-Former 8-384 (Table I widths, CIFAR geometry), traced and
+    not run: on the compiled-Pallas route every block goes through the
+    head-merged kernel and records one ``head_merged`` event; the oracle and
+    sparse routes split heads around their own SSA and record ``head_split``."""
+    cfg = sf.SPIKFORMER_8_384
+    params, state = sf.init(KEY, cfg)
+    img = jax.ShapeDtypeStruct((1, cfg.img_size, cfg.img_size, 3), jnp.float32)
+    calls = _count_calls(monkeypatch, "packed_ssa_op", "packed_ssa_merged_op",
+                         "sparse_packed_ssa_op")
+    plan = engine.compile_plan(params, state, cfg, backend=backend)
+    with _ssa_route_events() as seen:
+        jax.jit(engine.make_apply_fn(plan)).lower(plan.params, img)
+    assert seen == {route: cfg.num_layers}
+    merged = cfg.num_layers if route == "head_merged" else 0
+    assert calls["packed_ssa_merged_op"] == merged
+    assert calls["packed_ssa_op"] == 0
+    assert calls["sparse_packed_ssa_op"] == (
+        cfg.num_layers if engine.resolve_backend(backend).sparse else 0)
+
+
+def test_lm_packed_plan_keeps_head_split_kernel(monkeypatch):
+    """The spiking LM's causal SSA keeps the head-split ``packed_ssa_op``
+    and records no vision route event."""
+    from repro.models import spiking_lm as slm
+    from repro.models.lm import get_config
+
+    cfg = get_config("llama3.2-1b_smoke").replace(
+        spiking=True, spike_t=4, num_heads=4, head_dim=None)
+    params = slm.init_spiking_lm(KEY, cfg)
+    tokens = jax.ShapeDtypeStruct((2, 8), jnp.int32)
+    calls = _count_calls(monkeypatch, "packed_ssa_op", "packed_ssa_merged_op")
+    plan = engine.compile_plan(params, None, cfg, backend=PALLAS_PACKED_KERNEL)
+    with _ssa_route_events() as seen:
+        jax.jit(engine.make_apply_fn(plan)).lower(plan.params, tokens)
+    assert calls == {"packed_ssa_op": cfg.num_layers, "packed_ssa_merged_op": 0}
+    assert not seen
 
 
 def test_packed_plan_never_unpacks_under_closed_boundary(tiny_model, monkeypatch):

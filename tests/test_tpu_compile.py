@@ -5,7 +5,8 @@ kernel can pass every bit-exactness test and still be refused by the chip's
 compiler -- an unsupported cast, a block shape off the (8, 128) tiling, an
 SMEM/VMEM overrun.  These tests compile each kernel for a *described*
 ``v5e:2x2`` topology (no chip attached) at Spike-IAND-Former 8-768 widths:
-batch 8, 196 tokens, d=768, 12 heads, MLP 3072.
+batch 8, 196 tokens, d=768, 12 heads, MLP 3072 (and the head-merged SSA at
+8-384's d=384 and batch 1 as well).
 
 The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, and every test worker imports
@@ -89,6 +90,14 @@ def _case(name, t, s):
             lambda q, k, v: ssa_ops.sparse_packed_ssa_op(
                 q, k, v, t=t, interpret=False),
             qkv_words),
+        "packed_ssa_merged": (
+            lambda q, k, v: ssa_ops.packed_ssa_merged_op(
+                q, k, v, t=t, heads=H, interpret=False),
+            tuple(_spec(s, (1, B, N, D), u32) for _ in range(3))),
+        "packed_ssa_merged_8_384_b1": (
+            lambda q, k, v: ssa_ops.packed_ssa_merged_op(
+                q, k, v, t=t, heads=H, interpret=False),
+            tuple(_spec(s, (1, 1, N, D // 2), u32) for _ in range(3))),
         "lif": (
             lambda x: lif_ops.lif_parallel_op(x, interpret=False), (drive,)),
         "lif_pack": (
@@ -107,6 +116,8 @@ def _case(name, t, s):
     ("ssa", 4),
     ("packed_ssa", 4), ("packed_ssa", 32),
     ("sparse_packed_ssa", 4), ("sparse_packed_ssa", 32),
+    ("packed_ssa_merged", 4), ("packed_ssa_merged", 32),
+    ("packed_ssa_merged_8_384_b1", 4),
     ("lif", 4),
     ("lif_pack", 4), ("lif_iand_pack", 4),
 ])
