@@ -1,13 +1,14 @@
 """The control of ``correct``, and the readings that set its limits.
 
 The control is the plain reference computed one precision below the one the
-configuration states (int8 weights where it states bfloat16 operands,
-bfloat16 operands where it states float32), put in the executor's place
-through ``run.run_cell``'s ``fault`` hook: the window drives it at the
-cell's own load, and the harness's own comparison has to find it not
-correct.  For each seed this runs the cell as ``run.py`` does, for each
-control seed also with the control in the program's place, and prints one
-JSON line per run with its numbers and ``correct``.
+configuration states (the family driver's ``control``: for vision, int8
+weights where it states bfloat16 operands, bfloat16 operands where it states
+float32), put in the program's place through ``run.run_cell``'s ``fault``
+hook: the window drives it at the cell's own load, and the harness's own
+comparison has to find it not correct.  For each seed this runs the cell as
+``run.py`` does, for each control seed also with the control in the
+program's place, and prints one JSON line per run with its numbers and
+``correct``.
 
     python bench/control.py --workload sif-8-768.offline --seeds 1,2,3 \
         --control-seeds 1,2,3 --seconds 2
@@ -26,33 +27,11 @@ import run
 from benchlib import spec
 
 
-def control(cfg: dict, seed: int, block: int):
-    """A ``fault`` hook for ``run.run_cell``: the reference one precision
-    below the configuration's, on the benchmark's weights for ``seed``,
-    serving each batch in blocks of ``block`` rows."""
-    import jax
-    import jax.numpy as jnp
-
-    from benchlib import cell, model
-
-    arch = model.Arch.from_config(cfg)
-    params, state = cell.make_weights(cfg, seed)
-    folded = jax.jit(model.fold)(params, state)
-    operands = cfg["precision"]["matmul_operands"]
-    if operands == "bfloat16":
-        folded, operands = jax.jit(model.quantize_int8)(folded), "float32"
-    elif operands == "float32":
-        operands = "bfloat16"
-    else:
-        raise ValueError(f"no control below {operands!r} operands")
-    fn = jax.jit(lambda f, x: model.forward(None, None, x, arch, operands=operands,
-                                            folded=f))
-
-    def served(_params, x):
-        return jnp.concatenate([fn(folded, x[s:s + block])
-                                for s in range(0, x.shape[0], block)])
-
-    return lambda _compiled: served
+def control(cfg: dict, seed: int, traffic: dict):
+    """A ``fault`` hook for ``run.run_cell``: the configuration's family's
+    plain reference one precision below the configuration's, on the
+    benchmark's weights for ``seed``."""
+    return spec.load_family(cfg["family"]).control(cfg, seed, traffic)
 
 
 def main(argv=None) -> int:
@@ -74,14 +53,12 @@ def main(argv=None) -> int:
     control_seeds = [int(s) for s in args.control_seeds.split(",") if s]
     for seed, served_by in ([(s, "program") for s in seeds]
                             + [(s, "control") for s in control_seeds]):
-        fault = (control(cfg, seed, traffic["reference_block"])
-                 if served_by == "control" else None)
+        fault = control(cfg, seed, traffic) if served_by == "control" else None
         res = run.run_cell(args.workload, cfg, traffic, seed, args.seconds, False,
                            fault=fault)
         print(json.dumps({"seed": seed, "served_by": served_by,
                           "correct": res["correct"], "checks": res["checks"],
-                          "requests": res["attempted"] // traffic["batch"],
-                          "rates": res["extras"]["rates"]}), flush=True)
+                          "attempted": res["attempted"], **res["extras"]}), flush=True)
     return 0
 
 

@@ -2,19 +2,20 @@
 
     python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-The cell (``BENCHMARK.json``: configuration x traffic mix) is found by name.
-The run makes the weights and images from the seed, folds the weights into
-the program's ``pallas+packed`` deploy plan, compiles and warms the jitted
-executor (set-up), drives it for ``--seconds`` (the window), then checks what
-the window served against the benchmark's own plain reference.  With
-``--trace 1`` the profiler records the end of the window and the result
-carries the per-layer metrics; otherwise the end-to-end ones.
+The cell (``BENCHMARK.json``: configuration x traffic mix) is found by name,
+and its configuration's ``family`` names the driver (``bench/families/``)
+that knows the model: the run makes the weights and inputs from the seed,
+has the driver build and warm what the window drives (set-up), lets it serve
+for ``--seconds`` (the window), then has it check what the window served
+against the family's own plain reference.  With ``--trace 1`` the profiler
+records the end of the window and the result carries the per-layer metrics;
+otherwise the end-to-end ones.
 
 It refuses to run (exit 3, no result line) when JAX finds no TPU or fewer
 chips than the cell asks for.  Earlier lines report the compile cache's hits
-and misses, compilations inside the window, the batch size and the firing
-rate of every stage; the last lines of standard error give each number that
-decides ``correct`` beside its limit.
+and misses, compilations inside the window, the program's monitoring events
+and what the family logs; the last lines of standard error give each number
+that decides ``correct`` beside its limit.
 """
 
 from __future__ import annotations
@@ -32,8 +33,6 @@ import statistics  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 
-import numpy as np  # noqa: E402
-
 from benchlib import spec  # noqa: E402
 
 EXIT_NO_CHIP = 3
@@ -44,17 +43,27 @@ def log(msg: str) -> None:
 
 
 class Counters:
-    """Compile-cache hits/misses and backend compilations, from JAX's own
-    monitoring events."""
+    """Counts of JAX's monitoring events and the seconds of its duration
+    events: the compile cache's hits and misses, backend compilations, and
+    the program's own (``/repro/engine/compile_plan``,
+    ``/repro/engine/ssa_route/*``)."""
 
     def __init__(self):
         import jax
 
         self.events = collections.Counter()
+        self.seconds = collections.Counter()
         jax.monitoring.register_event_listener(
             lambda event, **_: self.events.update([event]))
-        jax.monitoring.register_event_duration_secs_listener(
-            lambda event, _secs, **_: self.events.update([event]))
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+
+    def _duration(self, event, secs, **_):
+        self.events[event] += 1
+        self.seconds[event] += secs
+
+    def summary(self) -> dict:
+        """{event: (count, summed seconds)}."""
+        return {k: (n, self.seconds[k]) for k, n in self.events.items()}
 
     @property
     def hits(self) -> int:
@@ -75,7 +84,7 @@ def enable_compile_cache() -> str:
     written to it however quickly it compiled."""
     import jax
 
-    from benchlib import cell  # noqa: F401  (puts the program on the path)
+    spec.program_on_path()
     from repro.launch.compile_info import enable_compile_cache as program_cache
 
     where = program_cache()
@@ -84,135 +93,75 @@ def enable_compile_cache() -> str:
     return where
 
 
-def image_pool(cfg: dict, traffic: dict, seed: int) -> np.ndarray:
-    """(pool, batch, H, W, C) float32 images, uniform in [0, 1), on the host."""
-    rng = np.random.default_rng(np.random.SeedSequence([seed, 1]))
-    shape = (traffic["pool"], traffic["batch"], cfg["img_size"], cfg["img_size"],
-             cfg["in_channels"])
-    return rng.random(shape, dtype=np.float32)
+class Window:
+    """The measured window, as every family's request loop runs it.
 
-
-def serve(step, params, pool: np.ndarray, traffic: dict, seed: int, seconds: float,
-          trace_dir: str | None, trace_seconds: float):
-    """The window: one client sends the pool's batches in turn, each as a
-    host array that is copied in, run, and whose logits come back to the
-    host.  Each request is sent when it is due (:mod:`benchlib.arrivals`;
-    in a closed loop, when the last one returned) or as soon as the client
-    is free after that, and is timed from when it was due.  With
+    ``due(offsets)`` yields ``(i, due)`` for each request of the traffic's
+    arrivals (:mod:`benchlib.arrivals`; an offset of None is a closed loop,
+    due when the client is free) until ``seconds`` have passed since the
+    window's ``start``; it waits until each request is due.  With
     ``trace_dir`` the profiler records the window's last ``trace_seconds``
-    and writes its trace once the window has closed, so the requests before
-    it run untraced.  Returns the window's start, per-request (pool index,
-    due, done, logits) and the number of requests sent before the profiler
-    started."""
-    import jax
+    (``sent``, the requests before that, run untraced) and writes its trace
+    once the window has closed.  ``request()`` is the ``bench.request`` span
+    that each request's work runs in."""
 
-    from benchlib import arrivals
+    def __init__(self, seconds: float, trace_dir: str | None, trace_seconds: float):
+        import jax
 
-    rows, lags = [], []
-    untraced = None
-    start = time.perf_counter()
-    for i, offset in enumerate(arrivals.due_times(traffic, seed)):
-        now = time.perf_counter()
-        due = now if offset is None else start + offset
-        if max(now, due) - start >= seconds:
-            break
-        if (trace_dir is not None and untraced is None
-                and max(now, due) - start >= seconds - trace_seconds):
-            opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 0     # no Python calls
-            opts.host_tracer_level = 1       # the benchmark's spans, not the runtime's
-            jax.profiler.start_trace(trace_dir, profiler_options=opts)
-            untraced = len(rows)
-            now = time.perf_counter()
-        while now < due:
-            now = time.perf_counter()
-        lags.append(now - due)
-        j = i % len(pool)
-        with jax.profiler.TraceAnnotation("bench.request"):
-            with jax.profiler.TraceAnnotation("bench.copy_in"):
-                x = jax.device_put(pool[j])
-            with jax.profiler.TraceAnnotation("bench.dispatch"):
-                out = step(params, x)
-            with jax.profiler.TraceAnnotation("bench.fetch"):
-                logits = np.asarray(out)
-        rows.append((j, due, time.perf_counter(), logits))
-    if untraced is not None:
-        jax.profiler.stop_trace()
-    if traffic["arrival"] != "closed":
-        log(f"requests sent after they were due: {sum(g > 1e-3 for g in lags)} of "
-            f"{len(rows)} by more than 1 ms, at most {max(lags) * 1e3:.3f} ms")
-    return start, rows, len(rows) if untraced is None else untraced
+        self._span = jax.profiler.TraceAnnotation
+        self.seconds = seconds
+        self.trace_dir = trace_dir
+        self.trace_seconds = trace_seconds
+        self.start = None
+        self.sent = 0
+        self.untraced = None
+
+    def due(self, offsets):
+        import jax
+
+        lags, scheduled = [], False
+        start = self.start = time.perf_counter()
+        try:
+            for i, offset in enumerate(offsets):
+                now = time.perf_counter()
+                scheduled |= offset is not None
+                due = now if offset is None else start + offset
+                if max(now, due) - start >= self.seconds:
+                    break
+                if (self.trace_dir is not None and self.untraced is None
+                        and max(now, due) - start >= self.seconds - self.trace_seconds):
+                    opts = jax.profiler.ProfileOptions()
+                    opts.python_tracer_level = 0     # no Python calls
+                    opts.host_tracer_level = 1       # the benchmark's spans, not the runtime's
+                    jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
+                    self.untraced = self.sent
+                    now = time.perf_counter()
+                while now < due:
+                    now = time.perf_counter()
+                lags.append(now - due)
+                self.sent += 1
+                yield i, due
+        finally:
+            if self.untraced is not None:
+                jax.profiler.stop_trace()
+        if scheduled and lags:
+            log(f"requests sent after they were due: {sum(g > 1e-3 for g in lags)} of "
+                f"{len(lags)} by more than 1 ms, at most {max(lags) * 1e3:.3f} ms")
+
+    def request(self):
+        return self._span("bench.request")
 
 
-def end_to_end(name: str, ctx: dict) -> float:
-    """The end-to-end metrics, taken by the harness on the host's clock."""
-    rows = ctx["rows"]
-    lat = [(done - due) * 1e3 for _, due, done, _ in rows]
+def end_to_end(name: str, ctx: dict, family) -> float:
+    """The end-to-end metrics, taken by the harness on the host's clock:
+    ``setup_s`` and ``latency_p50_ms`` (each request from when it was due to
+    when its result was on the host) for every family, the rest by the
+    family's driver."""
     if name == "setup_s":
         return ctx["setup_s"]
-    if name == "images_per_s":
-        return len(rows) * ctx["batch"] / (rows[-1][2] - ctx["start"])
     if name == "latency_p50_ms":
-        return statistics.median(lat)
-    raise KeyError(f"no end-to-end metric {name!r} in the harness")
-
-
-def check(cfg: dict, traffic: dict, seed: int, params, state, pool,
-          rows) -> tuple[dict, dict]:
-    """Compare a sample of the images the window served with the plain
-    reference.  Returns ({number: (value, limit)}, extras)."""
-    import jax
-    import jax.numpy as jnp
-
-    from benchlib import model
-
-    arch = model.Arch.from_config(cfg)
-    limits = cfg["limits"]
-    first: dict[int, np.ndarray] = {}
-    mismatched = 0
-    for j, _, _, logits in rows:
-        if j in first:
-            mismatched += not np.array_equal(first[j], logits)
-        else:
-            first[j] = logits
-    served = [(j, r) for j in sorted(first) for r in range(traffic["batch"])]
-    rng = np.random.default_rng(np.random.SeedSequence([seed, 2]))
-    pick = rng.choice(len(served), size=min(traffic["sample"], len(served)),
-                      replace=False)
-    pick = [served[p] for p in sorted(pick)]
-    imgs = np.stack([pool[j][r] for j, r in pick])
-    got = np.stack([first[j][r] for j, r in pick])
-
-    operands = cfg["precision"]["matmul_operands"]
-    folded = jax.jit(model.fold)(params, state)
-    ref_fn = jax.jit(lambda f, x: model.forward(None, None, x, arch, operands=operands,
-                                                folded=f, rates=True))
-    block = traffic["reference_block"]
-    refs, rates = [], collections.defaultdict(list)
-    for s in range(0, len(imgs), block):
-        chunk = imgs[s:s + block]
-        pad = block - len(chunk)
-        if pad:
-            chunk = np.concatenate([chunk, np.repeat(chunk[-1:], pad, 0)])
-        out, seen = ref_fn(folded, jnp.asarray(chunk))
-        refs.append(np.asarray(out)[:block - pad])
-        for k, v in seen.items():
-            rates[k].append(float(v))
-    ref = np.concatenate(refs)
-    extras = {"rates": {k: float(np.mean(v)) for k, v in rates.items()}}
-    numbers = gaps(got, ref)
-    numbers["repeat_mismatch"] = mismatched
-    for k in sorted(set(numbers) - set(limits)):
-        log(f"{k} (not compared): {numbers[k]!r}")
-    return {k: (v, limits[k]) for k, v in numbers.items() if k in limits}, extras
-
-
-def gaps(got: np.ndarray, ref: np.ndarray) -> dict:
-    """Per image, the widest logit gap over classes as a share of the
-    reference logits' RMS; their largest and median over the sample."""
-    rms = np.sqrt(np.mean(ref.astype(np.float64) ** 2, axis=1))
-    per = np.max(np.abs(got.astype(np.float64) - ref), axis=1) / rms
-    return {"gap_max": float(np.max(per)), "gap_median": float(np.median(per))}
+        return statistics.median([(row[2] - row[1]) * 1e3 for row in ctx["rows"]])
+    return family.end_to_end(name, ctx)
 
 
 def per_layer(cell: str, ctx: dict) -> dict:
@@ -228,19 +177,45 @@ def memory_peak(dev, compiled) -> int:
     """Peak device memory of the run, in bytes.  On the TPU the runtime
     counts buffers (``peak_bytes_in_use``) apart from the region it
     reserves for executors' temporaries (``peak_bytes_reserved``), so the
-    peak is their sum; where the compiler's account of the executor
+    peak is their sum; where the compiler's account of an executable
     (arguments, outputs and temporaries, live together while it runs) is
     larger, that is the peak."""
     stats = dev.memory_stats() or {}
-    mem = compiled.memory_analysis()
     footprint = 0
-    if mem is not None:
-        footprint = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-                     + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    for c in compiled:
+        mem = c.memory_analysis()
+        if mem is not None:
+            footprint = max(footprint, mem.argument_size_in_bytes + mem.output_size_in_bytes
+                            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     log("memory: " + " ".join(f"{k}={v}" for k, v in sorted(stats.items()))
         + f" executor_footprint={footprint}")
     runtime = stats.get("peak_bytes_in_use", 0) + stats.get("peak_bytes_reserved", 0)
     return int(max(runtime, footprint))
+
+
+def log_trace(ctx: dict, kernels: int) -> None:
+    """Device operations and Pallas kernels a request, beside the kernels the
+    executables hold (a trace that lost events shows fewer, and its rooflines
+    read too high), and device ms a request by top-level scope."""
+    from benchlib import traces
+
+    tr, lo, hi, table = ctx["trace"], ctx["lo"], ctx["hi"], ctx["op_scopes"]
+    _, calls = traces.span_ns(tr, "bench.request", lo, hi)
+    if calls == 0:
+        return
+    ops = kernel_ops = 0
+    by_scope = collections.Counter()
+    for name, s, d in tr["device"]:
+        if s + d <= lo or s >= hi:
+            continue
+        scope, is_kernel = table.get(name, (None, False))
+        ops += 1
+        kernel_ops += is_kernel
+        by_scope[scope.split("/")[0] if scope else "(none)"] += min(s + d, hi) - max(s, lo)
+    log(f"trace: {ops / calls:.1f} device ops and {kernel_ops / calls:.1f} Pallas kernel "
+        f"ops a request over {calls} requests; the executables hold {kernels} kernels")
+    log("device ms a request by top-level scope: " + " ".join(
+        f"{k}={v / calls / 1e6:.4f}" for k, v in sorted(by_scope.items())))
 
 
 def run_cell(cell: str, cfg: dict, traffic: dict, seed: int, seconds: float,
@@ -248,59 +223,62 @@ def run_cell(cell: str, cfg: dict, traffic: dict, seed: int, seconds: float,
              fault=None) -> dict:
     """One run of one cell; returns the result object (and ``extras``).
 
-    ``interpret`` runs the kernels in Pallas interpret mode (tests on the
-    CPU); ``fault(compiled)`` returns what the window drives in the
-    compiled executor's place (the control, and tests that break the timed
-    path)."""
+    The configuration's ``family`` names the driver (``bench/families/``)
+    that makes the weights and inputs, builds and warms what the window
+    drives, serves the window and checks it.  ``interpret`` runs the kernels
+    in Pallas interpret mode (tests on the CPU); ``fault`` is handed to the
+    family's ``build`` and returns what the window drives in the compiled
+    executable's place (the control, and tests that break the timed path)."""
     import jax
 
-    from benchlib import cell as program
-    from benchlib import model, traces
+    from benchlib import scopes, traces
 
+    family = spec.load_family(cfg["family"])
     counters = Counters()
     cache_dir = enable_compile_cache()
     dev = jax.devices()[0]
-    params, state = program.make_weights(cfg, seed)
-    jax.block_until_ready(params)
-    with program.precision_context(cfg):
-        plan, step = program.build(cfg, params, state, interpret=interpret)
-        pool = image_pool(cfg, traffic, seed)
-        compiled = step.lower(plan.params, pool[0]).compile()
-        kernels = compiled.as_text().count("tpu_custom_call")
-        if not interpret and kernels == 0:
-            raise RuntimeError("the compiled executor holds no Pallas kernel")
-        call = compiled if fault is None else fault(compiled)
-        for _ in range(2):
-            jax.block_until_ready(call(plan.params, jax.device_put(pool[0])))
-        log(f"cell {cell}: batch={traffic['batch']} pool={traffic['pool']} "
-            f"arrival={traffic['arrival']} tpu_custom_call={kernels}")
-        compiles_before = counters.compiles
-        setup_s = time.perf_counter() - T_START
-        trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
-        start, rows, untraced = serve(call, plan.params, pool, traffic, seed, seconds,
-                                      trace_dir, traffic["trace_seconds"])
-        in_window = counters.compiles - compiles_before
+    weights = family.make_weights(cfg, seed)
+    jax.block_until_ready(weights)
+    pool = family.pool(cfg, traffic, seed)
+    driven, compiled = family.build(cfg, weights, traffic, pool, interpret=interpret,
+                                    fault=fault)
+    texts = {name: c.as_text() for name, c in compiled.items()}
+    kernels = sum(t.count("tpu_custom_call") for t in texts.values())
+    if not interpret and kernels == 0:
+        raise RuntimeError("the compiled executables hold no Pallas kernel")
+    log(f"cell {cell}: executables {sorted(compiled)} tpu_custom_call={kernels}")
+    compiles_before = counters.compiles
+    setup_s = time.perf_counter() - T_START
+    trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
+    window = Window(seconds, trace_dir, traffic["trace_seconds"])
+    rows = family.serve(cfg, driven, pool, traffic, seed, window)
+    in_window = counters.compiles - compiles_before
     log(f"compile cache {cache_dir}: hits={counters.hits} misses={counters.misses}")
     log(f"compilations inside the window: {in_window}")
-    peak = memory_peak(dev, compiled)
-    ours = {id(a) for a in jax.tree_util.tree_leaves(params)}
-    for leaf in jax.tree_util.tree_leaves(plan.params):
-        if id(leaf) not in ours:       # free the program's folded weights
-            leaf.delete()
-    del compiled, call, step, plan
+    peak = memory_peak(dev, compiled.values())
+    op_scopes = {}
+    if trace:
+        for text in texts.values():
+            op_scopes.update(scopes.op_scopes(text))
+    ours = {id(a) for a in jax.tree_util.tree_leaves(weights)}
+    for leaf in jax.tree_util.tree_leaves(driven):
+        if isinstance(leaf, jax.Array) and id(leaf) not in ours:
+            leaf.delete()              # free the program's own state
+    del compiled, driven, texts
 
-    ctx = {"rows": rows, "start": start, "untraced": untraced, "batch": traffic["batch"],
-           "setup_s": setup_s, "memory_peak_bytes": peak,
-           "arch": model.Arch.from_config(cfg)}
+    ctx = {"rows": rows, "start": window.start,
+           "untraced": window.sent if window.untraced is None else window.untraced,
+           "setup_s": setup_s, "memory_peak_bytes": peak, "events": counters.summary(),
+           **family.context(cfg, traffic)}
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": peak}
-    result = {"correct": False, "attempted": len(rows) * traffic["batch"],
+    result = {"correct": False, "attempted": family.attempted(traffic, rows),
               "failed": 0, "metrics": {}, "device": device}
     if trace:
         tr = traces.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
         lo, hi = traces.window(tr)
-        ctx.update(trace=tr, lo=lo, hi=hi,
+        ctx.update(trace=tr, lo=lo, hi=hi, op_scopes=op_scopes,
                    peaks=peaks if peaks is not None else spec.peaks(dev.device_kind))
         device["busy_s"] = traces.busy_ns(tr["device"], lo, hi) / 1e9
         device["window_s"] = (hi - lo) / 1e9
@@ -309,24 +287,21 @@ def run_cell(cell: str, cfg: dict, traffic: dict, seed: int, seconds: float,
                                "idle_gaps": traces.idle_gaps(tr, lo, hi)}
         log(f"trace: {len(tr['device'])} device ops, {len(tr['spans'])} host spans, "
             f"window {device['window_s']:.3f}s, busy {device['busy_s']:.3f}s")
+        log_trace(ctx, kernels)
     else:
         for m in spec.cell_metrics(cell, "end_to_end"):
-            result["metrics"][m["name"]] = {"value": end_to_end(m["name"], ctx),
+            result["metrics"][m["name"]] = {"value": end_to_end(m["name"], ctx, family),
                                             "unit": m["unit"]}
+    log("program events: " + " ".join(
+        f"{k}={n}/{s:.3f}s" for k, (n, s) in sorted(ctx["events"].items())
+        if not k.startswith("/jax/")))
 
-    with program.precision_context(cfg):
-        numbers, extras = check(cfg, traffic, seed, params, state, pool, rows)
-    rates = extras["rates"]
-    log("firing rates: " + " ".join(
-        f"{k}={v:.4f}" for k, v in rates.items() if "." not in k))
-    log("branch rates (mean over blocks): " + " ".join(
-        f"{u}={np.mean([v for k, v in rates.items() if k.endswith('.' + u)]):.4f}"
-        for u in ("q", "k", "v", "attn", "proj", "fc1", "fc2")))
+    numbers, extras = family.check(cfg, traffic, seed, weights, pool, rows)
     result["correct"] = all(v <= lim for v, lim in numbers.values())
     result["failed"] = 0 if result["correct"] else result["attempted"]
     result["checks"] = {k: {"value": v, "limit": lim} for k, (v, lim) in numbers.items()}
     if trace:
-        extras["trace"] = ctx["trace"]
+        extras.update(trace=ctx["trace"], op_scopes=op_scopes)
     result["extras"] = extras
     return result
 

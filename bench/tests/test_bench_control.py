@@ -31,7 +31,7 @@ def harness():
 def test_control_in_the_programs_place_is_not_correct(harness, seed):
     cfg = spec.load_config("sif-8-384")
     cfg.update(SMOKE, precision=FLOAT32)
-    fault = control.control(cfg, seed, TRAFFIC["reference_block"])
+    fault = control.control(cfg, seed, TRAFFIC)
     res = harness.run_cell("sif-8-384.edge", cfg, TRAFFIC, seed, 0.3, False,
                            interpret=True, fault=fault)
     assert not res["correct"], res["checks"]
@@ -55,8 +55,9 @@ def test_int8_control_fails_every_configuration(seed):
                         operands="float32")
     same = model.forward(None, None, imgs, arch, folded=folded,
                          operands=cfg["precision"]["matmul_operands"])
-    assert run.gaps(np.asarray(same), np.asarray(ref))["gap_max"] == 0.0
-    got = run.gaps(np.asarray(ctl), np.asarray(ref))
+    gaps = spec.load_family("vision").gaps
+    assert gaps(np.asarray(same), np.asarray(ref))["gap_max"] == 0.0
+    got = gaps(np.asarray(ctl), np.asarray(ref))
     for c in spec.benchmark()["configs"]:
         limits = spec.load_config(c["name"])["limits"]
         assert any(got[k] > limits[k] for k in got if k in limits), (c["name"], got, limits)

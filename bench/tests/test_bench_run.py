@@ -3,6 +3,7 @@ and with the look for a chip skipped, a sound run of a small configuration
 comes out correct while a run whose timed path is broken does not."""
 
 import json
+import re
 import subprocess
 import sys
 
@@ -109,3 +110,44 @@ def test_p95_reader_takes_only_the_untraced_requests():
     rows += [(0, 40.0 + i, 40.0 + i + 0.5, None) for i in range(10)]    # traced, slower
     assert read({"rows": rows, "untraced": 40}) == pytest.approx(4.0)
     assert read({"rows": rows, "untraced": 5}) is None                  # too few to rank
+
+
+def test_ssa_roofline_reads_a_traced_run(run, capsys):
+    """The reader on a traced interpret-mode run's own spans and scope table:
+    the CPU trace holds no device operation, so each request is given one
+    operation of half its span, once an instruction of a ``block{i}/ssa``
+    scope (a value) and once one of ``head`` (None)."""
+    import fnmatch
+
+    from benchlib import model, ssa_work, traces
+
+    cfg, peaks = _smoke(), spec.peaks("TPU v5 lite")
+    res = run.run_cell("sif-8-384.edge", cfg, TRAFFIC, 11, 0.5, True,
+                       interpret=True, peaks=peaks)
+    assert res["correct"]
+    assert "ssa_roofline.edge" not in res["metrics"]         # no device ops on the CPU
+    events = capsys.readouterr().out
+    assert "/repro/engine/compile_plan=1/" in events
+    # one route event a block (interpret mode splits the heads)
+    assert re.search(rf"/repro/engine/ssa_route/head_(merged|split)={cfg['num_layers']}/",
+                     events)
+
+    tr, table = res["extras"]["trace"], res["extras"]["op_scopes"]
+    ssa = sorted(n for n, (s, _) in table.items() if s and fnmatch.fnmatchcase(s, "block*/ssa"))
+    head = sorted(n for n, (s, _) in table.items() if s == "head")
+    assert ssa and head
+    lo, hi = traces.window(tr)
+    reqs = [(s, d) for n, s, d in tr["spans"]
+            if n == "bench.request" and s >= lo and s + d <= hi]
+
+    def ctx(op):
+        trace = {"spans": tr["spans"], "device": [(op, s + d / 4, d / 2) for s, d in reqs]}
+        return {"trace": trace, "lo": lo, "hi": hi, "op_scopes": table, "peaks": peaks,
+                "arch": model.Arch.from_config(cfg), "batch": TRAFFIC["batch"]}
+
+    read = spec.load_metric("ssa_roofline.edge")
+    least, _ = ssa_work.ssa_least_s(model.Arch.from_config(cfg), TRAFFIC["batch"],
+                                    peaks["bf16_flops_per_s"], peaks["hbm_bytes_per_s"])
+    expect = 100 * len(reqs) * least / (sum(d / 2 for _, d in reqs) / 1e9)
+    assert read(ctx(ssa[0])) == pytest.approx(expect)
+    assert read(ctx(head[0])) is None

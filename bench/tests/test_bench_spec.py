@@ -59,3 +59,19 @@ def test_a_split_metric_falls_back_to_the_reader_of_its_quantity():
 
     assert where("executor_mfu.edge") == where("executor_mfu.offline")
     assert where("executor_mfu.edge").endswith("metrics/executor_mfu.py")
+
+
+CONTRACT = ("make_weights", "pool", "build", "serve", "attempted", "end_to_end",
+            "check", "context", "control", "rehearse")
+
+
+def test_every_configuration_names_a_family_that_keeps_the_contract():
+    for c in spec.benchmark()["configs"]:
+        family = spec.load_family(spec.load_config(c["name"])["family"])
+        assert all(callable(getattr(family, f)) for f in CONTRACT), c["name"]
+    assert spec.load_family("vision").__file__.endswith("families/vision.py")
+
+
+def test_an_unknown_family_is_refused_naming_the_missing_file():
+    with pytest.raises(KeyError, match=r"families/no-such-family\.py is missing"):
+        spec.load_family("no-such-family")

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchlib import cell, model, spec, work
+from benchlib import model, spec, work
 
 SMOKE = dict(img_size=32, num_classes=10, embed_dim=64, num_layers=2, num_heads=4,
              tokenizer_pools=[False, False, True, True])
@@ -31,7 +31,7 @@ def test_shapes_match_the_program_layout(name):
 
     cfg = _cfg(name)
     arch = model.Arch.from_config(cfg)
-    vcfg = cell.vision_config(cfg)
+    vcfg = spec.load_family("vision").vision_config(cfg)
     tcfg = vcfg.tokenizer_config()
     stages = tokenizer_layout(tcfg)
     grid = tokenizer_grid(tcfg, vcfg.img_size)
